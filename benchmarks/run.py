@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import xla_env
 from repro.analysis import contracts as CT
 from repro.configs import CNNS, HeliosConfig, reduced
 from repro.core import theory
@@ -75,6 +76,36 @@ def _run_scheme(world, scheme, n_capable, n_straggler, rounds, lr=0.02,
     else:
         hist = run.run_sync(rounds)
     return hist
+
+
+def _run_worker(module: str, args, env: dict, timeout: float):
+    """Run ``python -m module args`` as a worker with its own JAX.
+
+    A chip belongs to one process: a parent that has already started an
+    accelerator backend holds it, and a worker that needs it would then
+    fail or hang.  So that case is refused up front; workers pinned to the
+    CPU (``JAX_PLATFORMS=cpu``) never touch the chip and always run.
+    """
+    import subprocess
+    import sys
+    from jax._src import xla_bridge
+
+    if env.get("JAX_PLATFORMS") != "cpu" and \
+            xla_bridge.backends_are_initialized() and \
+            jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"benchmarks.run: this process already holds the "
+            f"{jax.default_backend()} device, so worker {module} could not "
+            f"use it; run this table alone (--only) in a fresh process")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(env, PYTHONPATH=os.path.join(repo, "src"))
+    r = subprocess.run([sys.executable, "-m", module, *args], env=env,
+                       cwd=repo, capture_output=True, text=True,
+                       timeout=timeout)
+    if r.returncode != 0:
+        raise RuntimeError(f"worker {module} exited {r.returncode}:\n"
+                           + r.stdout[-2000:] + r.stderr[-2000:])
+    return r.stdout
 
 
 def _acc_at_time(hist, t):
@@ -444,22 +475,14 @@ def table_sharded_population(devices=(1, 2, 4, 8, 16),
     so on small containers the sweep validates overhead, not speedup.
     """
     import json
-    import os as _os
-    import subprocess
-    import sys
-
-    repo = _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__)))
 
     def cell(n, dev):
-        env = dict(_os.environ, REPRO_HOST_DEVICES=str(dev),
-                   PYTHONPATH=_os.path.join(repo, "src"))
-        cmd = [sys.executable, "-m", "benchmarks.sharded_worker",
-               "--population", str(n), "--participation",
-               str(participation), "--rounds", str(rounds)]
-        r = subprocess.run(cmd, env=env, cwd=repo, capture_output=True,
-                           text=True, timeout=1800)
-        assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
-        line = [ln for ln in r.stdout.splitlines()
+        out = _run_worker(
+            "benchmarks.sharded_worker",
+            ["--population", str(n), "--participation", str(participation),
+             "--rounds", str(rounds)],
+            dict(os.environ, REPRO_HOST_DEVICES=str(dev)), timeout=1800)
+        line = [ln for ln in out.splitlines()
                 if ln.startswith("SHARDED ")][-1]
         rec = json.loads(line[len("SHARDED "):])
         assert rec["compiled_programs"] == 1, rec   # no recompile per draw
@@ -476,12 +499,12 @@ def table_sharded_population(devices=(1, 2, 4, 8, 16),
     best = max(r["rounds_per_sec"] for r in sweep_dev)
     emit(f"sharded_population/N={mid}/device_sweep", 0.0,
          f"best_speedup_vs_1dev={best / base:.2f}x;"
-         f"cpu_cores={_os.cpu_count()}")
+         f"cpu_cores={os.cpu_count()}")
     with open(out_path, "w") as f:
         json.dump({
             "participation": participation, "rounds": rounds,
             "scheme": "helios", "sampler": "uniform",
-            "host_cpu_count": _os.cpu_count(),
+            "host_cpu_count": os.cpu_count(),
             "device_sweep": sweep_dev,
             "population_sweep": sweep_pop,
             "best_speedup_vs_1dev": best / base,
@@ -1014,7 +1037,6 @@ def bench_softtrain_flops():
     the paper's straggler acceleration mechanism on the MXU."""
     from repro.models.layers import mlp_fwd, mlp_spec
     from repro.models.module import init_params
-    from repro.parallel.hlo_analysis import cost_analysis_dict
 
     d, ff = 512, 2048
     spec = mlp_spec(d, ff, "silu")
@@ -1023,13 +1045,13 @@ def bench_softtrain_flops():
 
     full = jax.jit(lambda p, x: mlp_fwd(p, x, "silu")).lower(
         params, x).compile()
-    base = cost_analysis_dict(full)["flops"]
+    base = full.cost_analysis()["flops"]
     for pfrac in (0.5, 0.25):
         k = int(ff * pfrac)
         idx = jnp.arange(k, dtype=jnp.int32)
         comp = jax.jit(lambda p, x, i: mlp_fwd(p, x, "silu", active_idx=i)
                        ).lower(params, x, idx).compile()
-        flops = cost_analysis_dict(comp)["flops"]
+        flops = comp.cost_analysis()["flops"]
         emit(f"softtrain/compact_mlp/P={pfrac}", 0.0,
              f"flop_fraction={flops / base:.3f}")
 
@@ -1057,22 +1079,14 @@ def table_million_population(populations=(10_000, 100_000, 1_000_000),
     against ``none`` — the accuracy price of each wire format.
     """
     import json
-    import os as _os
-    import subprocess
-    import sys
-
-    repo = _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__)))
 
     def cell(n, mode):
-        env = dict(_os.environ, PYTHONPATH=_os.path.join(repo, "src"))
-        cmd = [sys.executable, "-m", "benchmarks.million_worker",
-               "--population", str(n), "--participation",
-               str(participation), "--rounds", str(rounds),
-               "--mode", mode]
-        r = subprocess.run(cmd, env=env, cwd=repo, capture_output=True,
-                           text=True, timeout=3600)
-        assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
-        line = [ln for ln in r.stdout.splitlines()
+        out = _run_worker(
+            "benchmarks.million_worker",
+            ["--population", str(n), "--participation", str(participation),
+             "--rounds", str(rounds), "--mode", mode],
+            dict(os.environ), timeout=3600)
+        line = [ln for ln in out.splitlines()
                 if ln.startswith("MILLION ")][-1]
         rec = json.loads(line[len("MILLION "):])
         assert rec["compiled_programs"] == 1, rec   # no recompile per draw
@@ -1126,7 +1140,7 @@ def table_million_population(populations=(10_000, 100_000, 1_000_000),
         json.dump({
             "participation": participation, "rounds": rounds,
             "scheme": "helios", "host_budget_bytes": host_budget_bytes,
-            "host_cpu_count": _os.cpu_count(),
+            "host_cpu_count": os.cpu_count(),
             "cells": cells,
             "uplink_reduction_at_max_n": reduction,
             "convergence": {"rounds": conv_rounds, "clients": 8,
@@ -1168,6 +1182,7 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--only", default=None)
     args, _ = ap.parse_known_args()
+    xla_env.use_compile_cache()
 
     only = args.only.split(",") if args.only else list(TABLES)
     for name in only:

@@ -10,8 +10,9 @@ host-device pattern tests/test_dryrun_small.py validates).
 """
 import os
 
-os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
-                           + os.environ.get("REPRO_HOST_DEVICES", "1"))
+from repro.xla_env import force_host_devices
+
+force_host_devices(os.environ.get("REPRO_HOST_DEVICES", "1"))
 
 import argparse
 import json

@@ -17,9 +17,10 @@ multi-device host:
 """
 import os
 
+from repro.xla_env import force_host_devices
+
 if os.environ.get("REPRO_HOST_DEVICES"):
-    os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
-                               + os.environ["REPRO_HOST_DEVICES"])
+    force_host_devices(os.environ["REPRO_HOST_DEVICES"])
 
 import argparse
 import time

@@ -25,7 +25,7 @@ TRACE_WRAPPERS = {
     "jax.checkpoint", "jax.remat", "jax.custom_vjp", "jax.custom_jvp",
     "jax.lax.scan", "jax.lax.map", "jax.lax.while_loop", "jax.lax.fori_loop",
     "jax.lax.cond", "jax.lax.switch", "jax.lax.associative_scan",
-    "jax.experimental.shard_map.shard_map",
+    "jax.shard_map",
     "jax.experimental.checkify.checkify",
     "jax.experimental.pallas.pallas_call",
 }

@@ -125,8 +125,10 @@ def select_masks(scores: Dict[str, jax.Array],
     grid is fine).
     """
     if block:
-        pooled = {k for k, u in scores.items()
-                  if u.shape[-1] >= 4 * block}
+        # a list, not a set: set order follows the per-process string hash,
+        # which would reorder the traced program and its compile-cache key
+        pooled = [k for k, u in scores.items()
+                  if u.shape[-1] >= 4 * block]
         if not pooled:
             # nothing qualifies for pooling: fall straight through to the
             # unit-granular path on the ORIGINAL key, so mask_block > 0 on

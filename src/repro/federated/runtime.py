@@ -50,7 +50,6 @@ from typing import Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
@@ -1831,7 +1830,7 @@ class ShardedFLRun(BatchedFLRun):
                         coords) + ctrl_out
             return (new_g, new_state, ratios, losses) + ctrl_out
 
-        # check_rep=False: remat checkpoint_name (transformer stacks) has no
+        # check_vma=False: remat checkpoint_name (transformer stacks) has no
         # replication rule on current JAX; the psum above still leaves
         # new_g replicated in practice
         in_specs = (P(), P("clients"), P("clients"), P("clients"),
@@ -1846,9 +1845,9 @@ class ShardedFLRun(BatchedFLRun):
             out_specs += (P("clients"), P())               # new_err, coords
         if scheme.uses_control:
             out_specs += (P("clients"), P())               # new rows, dc_sum
-        sharded = shard_map(
+        sharded = jax.shard_map(
             round_body, mesh=self._mesh,
-            in_specs=in_specs, out_specs=out_specs, check_rep=False)
+            in_specs=in_specs, out_specs=out_specs, check_vma=False)
         return jax.jit(sharded)
 
     # -- template hooks -------------------------------------------------

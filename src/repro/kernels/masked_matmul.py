@@ -6,12 +6,14 @@ so the TPU adaptation makes the sparsity STRUCTURAL: Helios selection is
 block-aligned (units chosen in groups of ``block_n``, a beyond-paper
 optimization recorded in DESIGN.md §2), and this kernel SKIPS whole masked
 column blocks: the (bm, bn) output tile for a dead block is written as zeros
-without loading W or running the MXU — compute and HBM traffic both drop by
-the volume fraction P, which is exactly the paper's edge-device speedup
-mechanism re-expressed for the MXU.
+without running the MXU, so compute drops by the volume fraction P — the
+paper's edge-device speedup mechanism re-expressed for the MXU.  The
+BlockSpec pipeline still copies a dead block's operands into VMEM, so HBM
+traffic does not drop yet.
 
 Grid: (M/bm, N/bn, K/bk), K innermost for accumulation.  ``block_alive`` is
-a precomputed flag vector (mask.reshape(-1, bn).any(1)).
+a precomputed flag vector (mask.reshape(-1, bn).any(1)), scalar-prefetched
+into SMEM so every grid point reads its own flag.
 
 One kernel body serves both directions of the soft-training VJP — only the
 grid axis the alive flag indexes differs:
@@ -35,12 +37,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(alive_ref, x_ref, w_ref, o_ref, acc_ref, *, n_k: int):
+def _kernel(alive_ref, x_ref, w_ref, o_ref, acc_ref, *, n_k: int,
+            alive_axis: int):
     """One (bm, bn) output tile; K-blocks arrive sequentially (innermost).
-    ``alive_ref`` holds this grid point's flag — which axis it came from is
-    decided by the BlockSpec index_map below."""
+    ``alive_ref`` is the whole flag vector, scalar-prefetched into SMEM;
+    ``alive_axis`` names the grid axis that indexes it (1 = N, 2 = K)."""
     k_idx = pl.program_id(2)
-    alive = alive_ref[0] != 0
+    alive = alive_ref[pl.program_id(alive_axis)] != 0
 
     @pl.when(k_idx == 0)
     def _init():
@@ -64,19 +67,23 @@ def _call(x, w, block_alive, alive_axis, block_m, block_n, block_k,
     assert m % block_m == 0 and n % block_n == 0 and k % block_k == 0, \
         (x.shape, w.shape, block_m, block_n, block_k)
     n_k = k // block_k
-    alive_spec = pl.BlockSpec((1,), (lambda i, j, kk: (j,)) if
-                              alive_axis == "n" else (lambda i, j, kk: (kk,)))
-    return pl.pallas_call(
-        functools.partial(_kernel, n_k=n_k),
+    # the flags ride in as a scalar-prefetch operand (SMEM), not as a rank-1
+    # VMEM block: Mosaic refuses a (1,) block of a longer vector
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(m // block_m, n // block_n, n_k),
         in_specs=[
-            alive_spec,
-            pl.BlockSpec((block_m, block_k), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((block_k, block_n), lambda i, j, kk: (kk, j)),
+            pl.BlockSpec((block_m, block_k), lambda i, j, kk, a: (i, kk)),
+            pl.BlockSpec((block_k, block_n), lambda i, j, kk, a: (kk, j)),
         ],
-        out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
+        out_specs=pl.BlockSpec((block_m, block_n),
+                               lambda i, j, kk, a: (i, j)),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
+    )
+    return pl.pallas_call(
+        functools.partial(_kernel, n_k=n_k, alive_axis=alive_axis),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         interpret=interpret,
     )(block_alive.astype(jnp.int32), x, w)
 
@@ -93,7 +100,7 @@ def masked_matmul(x: jax.Array, w: jax.Array, block_alive: jax.Array,
     Masked-out columns of the result are ZERO (matching W*mask semantics
     when the mask is block-aligned).
     """
-    return _call(x, w, block_alive, "n", block_m, block_n, block_k,
+    return _call(x, w, block_alive, 1, block_m, block_n, block_k,
                  interpret)
 
 
@@ -110,5 +117,5 @@ def masked_matmul_dk(x: jax.Array, w: jax.Array, block_alive: jax.Array,
     entries to be zero (true for masked-gradient cotangents dy·mask and for
     masked hidden activations h·mask).
     """
-    return _call(x, w, block_alive, "k", block_m, block_n, block_k,
+    return _call(x, w, block_alive, 2, block_m, block_n, block_k,
                  interpret)

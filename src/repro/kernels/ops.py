@@ -17,9 +17,10 @@ Shapes are padded up to block multiples internally (zero columns are dead
 blocks and get skipped), so callers never hit divisibility asserts; unit
 masks of any length are handled by :func:`block_align_mask`-style padding.
 
-On CPU (this container) kernels execute with ``interpret=True`` — the kernel
-body runs as traced JAX ops, bit-compatible semantics for correctness tests.
-On TPU they compile natively.  ``INTERPRET`` is derived from the backend.
+On CPU kernels execute with ``interpret=True`` — the kernel body runs as
+traced JAX ops, bit-compatible semantics for correctness tests.  On TPU they
+compile natively; :func:`_interpret` derives the mode from the backend and
+refuses any other platform.
 """
 from __future__ import annotations
 
@@ -40,7 +41,18 @@ REFERENCE = "reference"
 
 
 def _interpret() -> bool:
-    return jax.default_backend() == "cpu"
+    """Kernel execution mode, decided by the platform: the CPU runs the
+    kernel bodies in interpret mode (tests), the TPU compiles them with
+    Mosaic.  Any other platform has no Pallas-TPU lowering, so it raises
+    rather than silently taking one path or the other."""
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(f"Pallas kernels run on 'cpu' (interpret mode) or "
+                       f"'tpu' (native); this process's backend is "
+                       f"{backend!r}")
 
 
 def _free_block(n: int, cap: int = 128) -> int:

@@ -1,7 +1,9 @@
 import os
-os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
-                           + os.environ.get("REPRO_HOST_DEVICES", "512"))
-# ^ MUST run before any other import: jax locks the device count on first
+
+from repro.xla_env import force_host_devices
+
+force_host_devices(os.environ.get("REPRO_HOST_DEVICES", "512"))
+# ^ MUST run before jax is imported: jax locks the device count on first
 #   init.  Smoke tests / benches never import this module and see 1 device.
 
 # Multi-pod dry-run: lower + compile every (architecture x input shape) cell
@@ -89,7 +91,7 @@ def _runtime(cfg, shape, mesh) -> dict:
 
 def analyze(lowered, compiled, cfg, shape, mesh) -> dict:
     from repro.parallel.hlo_cost import pattern_bytes, weighted_cost
-    cost = HA.cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     # trip-count-weighted re-walk of the HLO (lax.scan bodies count x trips;
     # XLA's cost_analysis counts them once — see parallel/hlo_cost.py)
     hlo_text = compiled.as_text()

@@ -14,6 +14,13 @@ import jax
 import numpy as np
 
 
+def _mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes: its default is Explicit axes, under
+    which ``with_sharding_constraint`` in the model layers raises."""
+    return jax.make_mesh(shape, axes, axis_types=(
+        jax.sharding.AxisType.Auto,) * len(shape))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     # REPRO_MESH="4x4" / "2x2x4" overrides the chip count for scaled-down CI
     # runs of the same code path (tests/test_dryrun_small.py).
@@ -22,10 +29,10 @@ def make_production_mesh(*, multi_pod: bool = False):
         shape = tuple(int(x) for x in override.split("x"))
         axes = ("pod", "data", "model") if len(shape) == 3 else \
             ("data", "model")
-        return jax.make_mesh(shape, axes)
+        return _mesh(shape, axes)
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_client_mesh(max_shards: int | None = None):
@@ -48,7 +55,7 @@ def make_debug_mesh(n_devices: int | None = None, *, multi_pod: bool = False):
     """Small mesh over however many (host) devices exist — used by tests."""
     n = n_devices or len(jax.devices())
     if multi_pod and n >= 8:
-        return jax.make_mesh((2, 2, n // 4), ("pod", "data", "model"))
+        return _mesh((2, 2, n // 4), ("pod", "data", "model"))
     if n >= 4:
-        return jax.make_mesh((2, n // 2), ("data", "model"))
-    return jax.make_mesh((1, n), ("data", "model"))
+        return _mesh((2, n // 2), ("data", "model"))
+    return _mesh((1, n), ("data", "model"))

@@ -31,21 +31,6 @@ _COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                 "collective-permute")
 
 
-def cost_analysis_dict(compiled) -> Dict[str, float]:
-    """Normalize ``Compiled.cost_analysis()`` across JAX versions.
-
-    Older JAX returned one flat dict; current JAX returns a LIST with one
-    dict per computation (and either may be None/empty).  Callers always
-    want the flat {metric: float} view of the main program.
-    """
-    cost = compiled.cost_analysis()
-    if not cost:
-        return {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost)
-
-
 def _shape_bytes(type_str: str) -> int:
     """Total bytes of every array literal in an HLO type string (handles
     tuples)."""

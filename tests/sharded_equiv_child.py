@@ -10,8 +10,9 @@ JSON line per scheme with the pairwise max param diffs.
 """
 import os
 
-os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
-                           + os.environ.get("REPRO_HOST_DEVICES", "16"))
+from repro.xla_env import force_host_devices
+
+force_host_devices(os.environ.get("REPRO_HOST_DEVICES", "16"))
 
 import argparse
 import json

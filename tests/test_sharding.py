@@ -9,13 +9,8 @@ from repro.parallel import sharding as SH
 
 @pytest.fixture(scope="module")
 def mesh():
-    # AbstractMesh: the 16x16 production topology without real devices.
-    # Newer JAX takes (sizes, names); 0.4.x takes ((name, size), ...) pairs.
-    try:
-        return jax.sharding.AbstractMesh((16, 16), ("data", "model"))
-    except TypeError:
-        return jax.sharding.AbstractMesh(
-            (("data", 16), ("model", 16)))
+    # AbstractMesh: the 16x16 production topology without real devices
+    return jax.sharding.AbstractMesh((16, 16), ("data", "model"))
 
 
 def test_spec_divisible(mesh):

@@ -225,7 +225,6 @@ def test_staleness_weight_decreases():
 
 
 def test_hlo_weighted_cost_matches_unrolled():
-    from repro.parallel.hlo_analysis import cost_analysis_dict
     from repro.parallel.hlo_cost import weighted_cost
 
     def unrolled(x, w):
@@ -249,4 +248,4 @@ def test_hlo_weighted_cost_matches_unrolled():
     assert abs(fu - analytic) / analytic < 0.05
     assert abs(fs - analytic) / analytic < 0.05
     # XLA's own analysis under-counts the scanned program (the bug we fix)
-    assert cost_analysis_dict(cs)["flops"] < 0.5 * fs
+    assert cs.cost_analysis()["flops"] < 0.5 * fs
