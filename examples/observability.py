@@ -19,6 +19,14 @@ event log + run manifest the ``repro.obs`` CLI consumes:
 
 Capture a ``jax.profiler`` trace around one chosen round with
 ``REPRO_OBS_PROFILE=<round>`` (or ``profile_round=`` on the Recorder).
+
+Armed or not, every span also lands in an in-process ring that any code in
+the process reads with ``repro.obs.recent_spans()``: after a slow or
+stalled round, the last round's ``fl.round`` children say which phase took
+the time (``fl.sample``, ``fl.stack``, ``fl.dispatch``, ``fl.writeback``,
+``fl.adapt``, ``fl.evaluate``).  The script prints them last.  Their
+``start_ns``/``end_ns`` are ``time.time_ns()`` stamps, the clock of a
+``jax.profiler`` trace's ``profile_start_time``.
 """
 import argparse
 
@@ -26,7 +34,7 @@ from repro.configs import CNNS, HeliosConfig, reduced
 from repro.data.federated import partition_noniid
 from repro.data.synthetic import class_gaussian_images
 from repro.federated import BatchedFLRun, make_fleet, setup_clients
-from repro.obs import Recorder, load_events, render, summarize
+from repro.obs import Recorder, load_events, recent_spans, render, summarize
 
 
 def main():
@@ -71,6 +79,11 @@ def main():
           f"downlink {summ['downlink_mb']:.2f} MB ==")
     print("rerun with --profile-round 1 (or REPRO_OBS_PROFILE=1) to drop "
           "a jax.profiler trace next to the log")
+    # the span ring: where the last round's host time went, by phase
+    phases = [s for s in recent_spans()
+              if s.round == args.rounds - 1 and s.parent == "fl.round"]
+    print("last round by phase: " + ", ".join(
+        f"{s.name} {(s.end_ns - s.start_ns) * 1e-6:.1f} ms" for s in phases))
 
 
 if __name__ == "__main__":

@@ -38,6 +38,7 @@ from repro.core import contribution as C
 from repro.core import masking as MK
 from repro.models import build, default_runtime, logical_axes
 from repro.models.cnn import cnn_logits
+from repro.obs import recorder as OBS
 
 #: model families whose batch is a plain token stream {"tokens": (B, S)}
 TOKEN_FAMILIES = ("dense", "moe", "ssm", "hybrid")
@@ -102,7 +103,8 @@ class FamilyAdapter:
                for idx in idx_seq]
         if pad_to and pad_to > len(per):
             per = per + [per[0]] * (pad_to - len(per))
-        return jax.tree.map(lambda *xs: jnp.stack(xs), *per)
+        with OBS.span("fl.stack"):
+            return jax.tree.map(lambda *xs: jnp.stack(xs), *per)
 
     def eval_slice(self, data: Dict[str, np.ndarray], lo: int,
                    hi: int) -> dict:

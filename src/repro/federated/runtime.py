@@ -43,7 +43,6 @@ cross-engine equivalence contract is stated in exactly one place.
 from __future__ import annotations
 
 import dataclasses
-import time
 from collections import OrderedDict
 from typing import Dict, List, Optional, Sequence
 
@@ -596,14 +595,15 @@ class FLRun:
         and converts to host floats HERE, behind the eval gate — the
         run_sync hot loop itself never forces a device->host sync."""
         if eval_every > 0 and (r % eval_every == 0 or r == rounds - 1):
-            self.history.append({
-                "scheme": self.scheme, "cycle": r + 1, "time": clock,
-                "record_cadence": "round",
-                self.adapter.metric_name: self.evaluate(),
-                "loss": float(np.mean(np.asarray(losses))),
-                "ratios": [float(x) for x in np.asarray(ratios)],
-                "volumes": [c.volume for c in self.clients],
-                "downlink_mb": self.downlink_bytes() / 1e6})
+            with self.rec.span("fl.evaluate", sim=clock, round=r):
+                self.history.append({
+                    "scheme": self.scheme, "cycle": r + 1, "time": clock,
+                    "record_cadence": "round",
+                    self.adapter.metric_name: self.evaluate(),
+                    "loss": float(np.mean(np.asarray(losses))),
+                    "ratios": [float(x) for x in np.asarray(ratios)],
+                    "volumes": [c.volume for c in self.clients],
+                    "downlink_mb": self.downlink_bytes() / 1e6})
             row = self.history[-1]
             self.rec.event("history", sim=row["time"],
                            **{k: v for k, v in row.items() if k != "time"})
@@ -623,40 +623,8 @@ class FLRun:
         """
         clock = 0.0
         for r in range(rounds):
-            cohort = self._draw_cohort()
-            self.cohort_log.append(cohort)
-            cclients = [self.clients[i] for i in cohort]
-            pace = _collab_pace(cclients)
-            times = self._round_times(cclients)
-            self.rec.inc("downlink_updates", len(cohort))   # global broadcast
-            with self.rec.span("scheme.round_start", sim=clock, round=r):
-                self._scheme.round_start(self)
-            # contract: the round's device work never syncs to host —
-            # losses/ratios stay device values until _record_round's gate
-            with self.rec.maybe_profile(r), \
-                    self.rec.span("train_cohort", sim=clock, round=r), \
-                    CT.no_host_transfers("run_sync[" + self.scheme + "]"):
-                losses, ratios = self._train_cohort(cohort, cclients)
-            self.rec.inc("uplink_updates", len(cohort))
-            if self.compression != "none" and not self._comp_active():
-                self.rec.inc("uplink_dense_updates", len(cohort))  # warmup
-            self.rec.inc("uplink_extra_updates",
-                         len(cohort) * self._scheme.extra_dense_uplink)
-            CT.assert_finite(self.global_params, tag="run_sync.global_params")
-            self._adapt_volumes(cohort, cclients, times, pace)
-            with self.rec.span("scheme.round_end", sim=clock, round=r):
-                self._scheme.round_end(self)
-            dur = self._scheme.round_duration(times, cclients)
-            clock += dur
-            self.round += 1
-            self.rec.event("round", sim=clock, round=r, cohort=len(cohort),
-                           pace=pace, duration=dur)
-            self.rec.event("volumes", sim=clock, round=r,
-                           volumes=[self._scheme.effective_volume(c)
-                                    for c in cclients if c.is_straggler])
-            if self.publish_dir and (r + 1) % self.publish_every == 0:
-                self._publish_round(r, clock)
-            self._record_round(r, rounds, eval_every, clock, losses, ratios)
+            with self.rec.span("fl.round", sim=clock, round=r):
+                clock = self._sync_round(r, rounds, eval_every, clock)
         self._finish_sync()
         if CT.enabled():
             # one compiled program per seam per shape signature, and every
@@ -667,6 +635,48 @@ class FLRun:
                     masks, block=self.hcfg.mask_block, tag="run_sync.masks")
         self._obs_finish("run_sync")   # after the walls: counters complete
         return self.history
+
+    def _sync_round(self, r: int, rounds: int, eval_every: int,
+                    clock: float) -> float:
+        """One round of :meth:`run_sync`; returns the advanced clock.  Its
+        host phases are spans in the ring (``fl.sample``, ``fl.stack``,
+        ``fl.dispatch``, ``fl.writeback`` inside the batched engines'
+        ``_train_cohort``; ``fl.adapt``; ``fl.evaluate``; ``publish``);
+        the cohort draw and the scheme's ``round_start`` are the
+        enclosing ``fl.round``'s own time."""
+        cohort = self._draw_cohort()
+        self.cohort_log.append(cohort)
+        cclients = [self.clients[i] for i in cohort]
+        pace = _collab_pace(cclients)
+        times = self._round_times(cclients)
+        self.rec.inc("downlink_updates", len(cohort))   # global broadcast
+        self._scheme.round_start(self)
+        # contract: the round's device work never syncs to host —
+        # losses/ratios stay device values until _record_round's gate
+        with self.rec.maybe_profile(r), \
+                CT.no_host_transfers("run_sync[" + self.scheme + "]"):
+            losses, ratios = self._train_cohort(cohort, cclients)
+        self.rec.inc("uplink_updates", len(cohort))
+        if self.compression != "none" and not self._comp_active():
+            self.rec.inc("uplink_dense_updates", len(cohort))  # warmup
+        self.rec.inc("uplink_extra_updates",
+                     len(cohort) * self._scheme.extra_dense_uplink)
+        CT.assert_finite(self.global_params, tag="run_sync.global_params")
+        with self.rec.span("fl.adapt", sim=clock, round=r):
+            self._adapt_volumes(cohort, cclients, times, pace)
+            self._scheme.round_end(self)
+        dur = self._scheme.round_duration(times, cclients)
+        clock += dur
+        self.round += 1
+        self.rec.event("round", sim=clock, round=r, cohort=len(cohort),
+                       pace=pace, duration=dur)
+        self.rec.event("volumes", sim=clock, round=r,
+                       volumes=[self._scheme.effective_volume(c)
+                                for c in cclients if c.is_straggler])
+        if self.publish_dir and (r + 1) % self.publish_every == 0:
+            self._publish_round(r, clock)
+        self._record_round(r, rounds, eval_every, clock, losses, ratios)
+        return clock
 
     # -- engine hooks ---------------------------------------------------
     def _train_cohort(self, cohort: List[int], cclients: List[Client]):
@@ -1156,7 +1166,6 @@ class AsyncFLRun(FLRun):
                 CT.check_staleness(stales, a=staleness_a,
                                    tag="run_async[bucket]")
                 pad = bpad - b
-                _bt0 = time.perf_counter() if self.rec.armed else 0.0
                 bucket_fn = self._get_bucket_fn(bpad)
                 if self.compression == "none":
                     with CT.no_host_transfers("run_async[bucket]"):
@@ -1206,10 +1215,6 @@ class AsyncFLRun(FLRun):
                 self.bucket_sizes.append(b)
                 self.rec.observe("bucket_size", b)
                 self.rec.observe("queue_depth", len(clock))
-                self.rec.event(
-                    "bucket", sim=clock.now, size=b, pad=bpad - b,
-                    queue=len(clock),
-                    wall_ms=(time.perf_counter() - _bt0) * 1e3)
                 done_fast += sum(1 for ev in exec_evs
                                  if not by_id[ev.cid].is_straggler)
             # reschedule every handled event in event order (arrival-stream
@@ -1296,7 +1301,7 @@ class BatchedFLRun(AsyncFLRun):
         if self.participation:
             # sampled cohorts change membership per round: per-client
             # ``helios_state`` stays authoritative and each round stacks /
-            # unstacks just its cohort (_train_cohort_sampled) — no
+            # unstacks just its cohort (_train_cohort) — no
             # persistent whole-fleet stacked state to fall out of sync
             self._sstate = None
             return
@@ -1355,106 +1360,97 @@ class BatchedFLRun(AsyncFLRun):
                     st = ST.end_cycle(st, scores, hcfg_eff)
                     return (p, st, MK.selected_fraction(masks), loss, masks)
 
-                p, new_sstate, r, l, m = jax.vmap(one_straggler)(
-                    sstate, s_batch)
+                # named scopes tag the device ops with the round's parts
+                # (a trace's ``tf_op`` paths; bench/program_trace.py)
+                with jax.named_scope("fl_straggler_train"):
+                    p, new_sstate, r, l, m = jax.vmap(one_straggler)(
+                        sstate, s_batch)
                 parts_p.append(p), parts_r.append(r), parts_l.append(l)
                 parts_m.append(m)
             if n_c:
-                if scheme.uses_control:
-                    corr = jax.tree.map(lambda cg, cr: cg - cr,
-                                        c_global, c_rows)
+                with jax.named_scope("fl_capable_train"):
+                    if scheme.uses_control:
+                        corr = jax.tree.map(lambda cg, cr: cg - cr,
+                                            c_global, c_rows)
 
-                    def one_capable(batches, co):
-                        return local_train(global_params, batches,
-                                           ones_masks, co)
+                        def one_capable(batches, co):
+                            return local_train(global_params, batches,
+                                               ones_masks, co)
 
-                    p, l = jax.vmap(one_capable)(c_batch, corr)
-                elif scheme.uses_stale_base:
-                    def one_capable(batches, flag, disc):
-                        base = jax.tree.map(
-                            lambda s, g: jnp.where(flag > 0,
-                                                   s.astype(g.dtype), g),
-                            stale_base, global_params)
-                        p, loss = local_train(base, batches, ones_masks)
-                        # virtualize onto the current global (capable rows:
-                        # base == global, disc == 1 => exactly p)
-                        p = jax.tree.map(
-                            lambda g, y, b: (g.astype(jnp.float32) + disc
-                                             * (y.astype(jnp.float32)
-                                                - b.astype(jnp.float32))
-                                             ).astype(g.dtype),
-                            global_params, p, base)
-                        return p, loss
+                        p, l = jax.vmap(one_capable)(c_batch, corr)
+                    elif scheme.uses_stale_base:
+                        def one_capable(batches, flag, disc):
+                            base = jax.tree.map(
+                                lambda s, g: jnp.where(flag > 0,
+                                                       s.astype(g.dtype), g),
+                                stale_base, global_params)
+                            p, loss = local_train(base, batches, ones_masks)
+                            # virtualize onto the current global (capable rows:
+                            # base == global, disc == 1 => exactly p)
+                            p = jax.tree.map(
+                                lambda g, y, b: (g.astype(jnp.float32) + disc
+                                                 * (y.astype(jnp.float32)
+                                                    - b.astype(jnp.float32))
+                                                 ).astype(g.dtype),
+                                global_params, p, base)
+                            return p, loss
 
-                    p, l = jax.vmap(one_capable)(c_batch, stale_flags,
-                                                 discs)
-                else:
-                    def one_capable(batches):
-                        return local_train(global_params, batches,
-                                           ones_masks)
+                        p, l = jax.vmap(one_capable)(c_batch, stale_flags,
+                                                     discs)
+                    else:
+                        def one_capable(batches):
+                            return local_train(global_params, batches,
+                                               ones_masks)
 
-                    p, l = jax.vmap(one_capable)(c_batch)
+                        p, l = jax.vmap(one_capable)(c_batch)
                 parts_p.append(p)
                 parts_r.append(jnp.ones((n_c,), jnp.float32))
                 parts_l.append(l)
                 parts_m.append(jax.tree.map(
                     lambda v: jnp.ones((n_c,) + v.shape, jnp.float32),
                     ones_masks))
-            stacked = cat(parts_p)
-            ratios = cat(parts_r)
-            losses = cat(parts_l)
-            ctrl_out = ()
-            if scheme.uses_control:
-                # option-II control update from the RAW trained rows,
-                # before any codec touches them
-                dc = jax.tree.map(
-                    lambda g, t, cg: (g.astype(jnp.float32)
-                                      - t.astype(jnp.float32)) * inv - cg,
-                    global_params, stacked, c_global)
-                new_c_rows = jax.tree.map(lambda rr, d: rr + d, c_rows, dc)
-                dc_sum = jax.tree.map(lambda d: jnp.sum(d, axis=0), dc)
-                ctrl_out = (new_c_rows, dc_sum)
-            if comp == "none":
-                pmasks = adapter.expand_masks_batch(cat(parts_m),
-                                                    global_params) \
-                    if agg_mode == "masked_mean" else None
+            with jax.named_scope("fl_aggregate"):
+                stacked = cat(parts_p)
+                ratios = cat(parts_r)
+                losses = cat(parts_l)
+                ctrl_out = ()
+                if scheme.uses_control:
+                    # option-II control update from the RAW trained rows,
+                    # before any codec touches them
+                    dc = jax.tree.map(
+                        lambda g, t, cg: (g.astype(jnp.float32)
+                                          - t.astype(jnp.float32)) * inv - cg,
+                        global_params, stacked, c_global)
+                    new_c_rows = jax.tree.map(lambda rr, d: rr + d, c_rows, dc)
+                    dc_sum = jax.tree.map(lambda d: jnp.sum(d, axis=0), dc)
+                    ctrl_out = (new_c_rows, dc_sum)
+                if comp == "none":
+                    pmasks = adapter.expand_masks_batch(cat(parts_m),
+                                                        global_params) \
+                        if agg_mode == "masked_mean" else None
+                    new_global = AG.aggregate_stacked(agg_mode, global_params,
+                                                      stacked, ratios, pmasks)
+                    return (new_global, new_sstate, ratios, losses) + ctrl_out
+                # compressed uplink: every stacked update goes through the
+                # codec + error feedback, masked so Eq. 2-frozen coordinates
+                # are never encoded (capable rows carry ones masks)
+                pm = adapter.expand_masks_batch(cat(parts_m), global_params)
+                delta = jax.tree.map(
+                    lambda t, g: t.astype(jnp.float32) - g.astype(jnp.float32),
+                    stacked, global_params)
+                sent, new_err, coords = jax.vmap(
+                    lambda d, e, m: CP.compress_update(d, e, comp, frac, bits,
+                                                       m))(delta, err, pm)
+                stacked = jax.tree.map(
+                    lambda g, s: (g.astype(jnp.float32) + s).astype(g.dtype),
+                    global_params, sent)
+                pmasks = pm if agg_mode == "masked_mean" else None
                 new_global = AG.aggregate_stacked(agg_mode, global_params,
                                                   stacked, ratios, pmasks)
-                return (new_global, new_sstate, ratios, losses) + ctrl_out
-            # compressed uplink: every stacked update goes through the
-            # codec + error feedback, masked so Eq. 2-frozen coordinates
-            # are never encoded (capable rows carry ones masks)
-            pm = adapter.expand_masks_batch(cat(parts_m), global_params)
-            delta = jax.tree.map(
-                lambda t, g: t.astype(jnp.float32) - g.astype(jnp.float32),
-                stacked, global_params)
-            sent, new_err, coords = jax.vmap(
-                lambda d, e, m: CP.compress_update(d, e, comp, frac, bits,
-                                                   m))(delta, err, pm)
-            stacked = jax.tree.map(
-                lambda g, s: (g.astype(jnp.float32) + s).astype(g.dtype),
-                global_params, sent)
-            pmasks = pm if agg_mode == "masked_mean" else None
-            new_global = AG.aggregate_stacked(agg_mode, global_params,
-                                              stacked, ratios, pmasks)
-            return (new_global, new_sstate, ratios, losses, new_err,
-                    jnp.sum(coords)) + ctrl_out
+                return (new_global, new_sstate, ratios, losses, new_err,
+                        jnp.sum(coords)) + ctrl_out
 
         return round_fn
-
-    # ------------------------------------------------------------------
-    def _sample_cohort_batches(self):
-        # consume self.rng in CLIENT order — bit-identical draws to the
-        # sequential engine's per-client loop
-        per = [self._sample_batches(c) for c in self.clients]
-
-        def stack(idx):
-            if not idx:
-                return None
-            return jax.tree.map(lambda *xs: jnp.stack(xs),
-                                *[per[i] for i in idx])
-
-        return stack(self._s_idx), stack(self._c_idx)
 
     # -- template hooks -------------------------------------------------
     def _round_extras(self, row_clients: List[Client]):
@@ -1486,79 +1482,65 @@ class BatchedFLRun(AsyncFLRun):
                                           self._c_global, dc_sum)
 
     def _train_cohort(self, cohort: List[int], cclients: List[Client]):
-        if self.participation:
-            return self._train_cohort_sampled(cohort, cclients)
-        s_batch, c_batch = self._sample_cohort_batches()
-        round_fn = self._get_round_fn(len(self._s_idx), len(self._c_idx))
-        extras = self._round_extras(self.clients)
-        if not self._comp_active():
-            outs = round_fn(self.global_params, self._sstate,
-                            s_batch, c_batch, self._unperm, *extras)
-            self.global_params, self._sstate, ratios, losses = outs[:4]
-            self._apply_round_outs(self.clients, outs[4:])
-            return losses, ratios
-        # stacked rows are in original client order (cat() un-permutes),
-        # so the error rows gather/scatter in that same order
-        cids = [c.cid for c in self.clients]
-        err = self._err_store.gather(cids)
-        outs = round_fn(self.global_params, self._sstate,
-                        s_batch, c_batch, self._unperm, *extras, err)
-        (self.global_params, self._sstate, ratios, losses, new_err,
-         coords) = outs[:6]
-        self.rec.accum("uplink_coords", coords)
-        self._err_store.scatter(cids, new_err)
-        self._apply_round_outs(self.clients, outs[6:])
-        # device arrays on purpose — _record_round converts behind the gate
-        return losses, ratios
+        """Stack the cohort, run the (n_s, n_c)-shaped round program from
+        the LRU cache, and write its outputs back.
 
-    def _train_cohort_sampled(self, cohort: List[int],
-                              cclients: List[Client]):
-        """Partial participation: stack just the drawn cohort.
-
-        Per-client ``helios_state`` is the source of truth between rounds
-        (unsampled clients' state is literally untouched); the cohort's
-        straggler rows are stacked, run through the (n_s, n_c)-shaped round
-        program from the LRU cache, and unstacked back.  Batch draws
-        consume ``self.rng`` in cohort order — the same order as the
-        sequential engine's loop — so trajectories stay replay-equivalent.
+        Full participation keeps the stragglers' Helios state stacked
+        across rounds (``self._sstate``).  Under partial participation
+        per-client ``helios_state`` is the source of truth between rounds
+        (unsampled clients' state is literally untouched): the cohort's
+        straggler rows are stacked and unstacked back.  Batch draws consume
+        ``self.rng`` in cohort order — the same order as the sequential
+        engine's loop — so trajectories stay replay-equivalent.
         """
-        soft = self._scheme.soft_training
-        s_pos = [j for j, c in enumerate(cclients)
-                 if soft and c.is_straggler]
-        c_pos = [j for j, c in enumerate(cclients)
-                 if not (soft and c.is_straggler)]
-        unperm = jnp.asarray(np.argsort(np.asarray(s_pos + c_pos)),
-                             jnp.int32)
-        per = [self._sample_batches(c) for c in cclients]
-
-        def stack(pos):
-            if not pos:
-                return None
-            return jax.tree.map(lambda *xs: jnp.stack(xs),
-                                *[per[j] for j in pos])
-
-        sstate = ST.stack_states([cclients[j].helios_state
-                                  for j in s_pos]) if s_pos else None
-        round_fn = self._get_round_fn(len(s_pos), len(c_pos))
-        extras = self._round_extras(cclients)
-        if not self._comp_active():
-            outs = round_fn(self.global_params, sstate, stack(s_pos),
-                            stack(c_pos), unperm, *extras)
-            self.global_params, sstate, ratios, losses = outs[:4]
-            self._apply_round_outs(cclients, outs[4:])
+        comp = self._comp_active()
+        if self.participation:
+            soft = self._scheme.soft_training
+            s_pos = [j for j, c in enumerate(cclients)
+                     if soft and c.is_straggler]
+            c_pos = [j for j, c in enumerate(cclients)
+                     if not (soft and c.is_straggler)]
         else:
+            s_pos, c_pos = self._s_idx, self._c_idx
+        with self.rec.span("fl.sample"):
+            per = [self._sample_batches(c) for c in cclients]
+        with self.rec.span("fl.stack"):
+            def stack(pos):
+                if not pos:
+                    return None
+                return jax.tree.map(lambda *xs: jnp.stack(xs),
+                                    *[per[j] for j in pos])
+
+            if self.participation:
+                unperm = jnp.asarray(np.argsort(np.asarray(s_pos + c_pos)),
+                                     jnp.int32)
+                sstate = ST.stack_states([cclients[j].helios_state
+                                          for j in s_pos]) if s_pos else None
+            else:
+                unperm, sstate = self._unperm, self._sstate
+            args = (self.global_params, sstate, stack(s_pos), stack(c_pos),
+                    unperm) + self._round_extras(cclients)
+            # stacked rows are in cohort order (cat() un-permutes), so the
+            # error rows gather/scatter in that same order
             cids = [c.cid for c in cclients]
-            err = self._err_store.gather(cids)
-            outs = round_fn(self.global_params, sstate, stack(s_pos),
-                            stack(c_pos), unperm, *extras, err)
-            (self.global_params, sstate, ratios, losses, new_err,
-             coords) = outs[:6]
-            self.rec.accum("uplink_coords", coords)
-            self._err_store.scatter(cids, new_err)
-            self._apply_round_outs(cclients, outs[6:])
-        if s_pos:
-            for j, st in zip(s_pos, ST.unstack_states(sstate, len(s_pos))):
-                cclients[j].helios_state = st
+            if comp:
+                args += (self._err_store.gather(cids),)
+            round_fn = self._get_round_fn(len(s_pos), len(c_pos))
+        with self.rec.span("fl.dispatch"):
+            outs = round_fn(*args)
+        with self.rec.span("fl.writeback"):
+            self.global_params, sstate, ratios, losses = outs[:4]
+            if comp:
+                new_err, coords = outs[4:6]
+                self.rec.accum("uplink_coords", coords)
+                self._err_store.scatter(cids, new_err)
+            self._apply_round_outs(cclients, outs[6 if comp else 4:])
+            if not self.participation:
+                self._sstate = sstate
+            elif s_pos:
+                for j, st in zip(s_pos,
+                                 ST.unstack_states(sstate, len(s_pos))):
+                    cclients[j].helios_state = st
         # device arrays on purpose — _record_round converts behind the gate
         return losses, ratios
 
@@ -1766,69 +1748,75 @@ class ShardedFLRun(BatchedFLRun):
                 row_extra = (corr,)
             elif scheme.uses_stale_base:
                 row_extra = (stale_flags, discs)
-            p, new_state, ratios, losses, masks = jax.vmap(one_client)(
-                cstate, batches, is_soft, *row_extra)
-            ctrl_out = ()
-            if scheme.uses_control:
-                # option-II control update from the RAW trained rows;
-                # padding rows are masked out of the server fold by valid
-                dc = jax.tree.map(
-                    lambda g, t, cg: (g.astype(jnp.float32)
-                                      - t.astype(jnp.float32)) * inv - cg,
-                    global_params, p, c_global)
-                new_c_rows = jax.tree.map(lambda rr, d: rr + d, c_rows, dc)
-                dc_sum = jax.tree.map(
-                    lambda d: jax.lax.psum(
-                        jnp.sum(d * valid.reshape((-1,) + (1,)
-                                                  * (d.ndim - 1)), axis=0),
-                        "clients"), dc)
-                ctrl_out = (new_c_rows, dc_sum)
-            pm = adapter.expand_masks_batch(masks, global_params) \
-                if (comp != "none" or agg_mode == "masked_mean") else None
-            if comp != "none":
-                # codec runs shard-local on each device's cohort rows;
-                # only the coordinate count crosses devices (one psum)
-                delta = jax.tree.map(
-                    lambda t, g: t.astype(jnp.float32)
-                    - g.astype(jnp.float32), p, global_params)
-                sent, new_err, coords = jax.vmap(
-                    lambda d, e, m: CP.compress_update(d, e, comp, frac,
-                                                       bits, m))(
-                        delta, err, pm)
-                p = jax.tree.map(
-                    lambda g, s: (g.astype(jnp.float32) + s).astype(g.dtype),
-                    global_params, sent)
-                coords = jax.lax.psum(jnp.sum(coords * valid), "clients")
-            base = ratios if agg_mode != "uniform" else jnp.ones_like(ratios)
-            w = base * valid
-            a = w / jnp.maximum(jax.lax.psum(jnp.sum(w), "clients"), 1e-9)
-            if agg_mode == "masked_mean":
-                pmasks = pm
-                num = jax.tree.map(
-                    lambda m, t: jnp.sum(
-                        a.reshape((-1,) + (1,) * (t.ndim - 1)) * m
-                        * t.astype(jnp.float32), axis=0), pmasks, p)
-                den = jax.tree.map(
-                    lambda m: jnp.sum(
-                        a.reshape((-1,) + (1,) * (m.ndim - 1)) * m, axis=0),
-                    pmasks)
-                num, den = jax.lax.psum((num, den), "clients")
-                new_g = jax.tree.map(
-                    lambda g, nu, de: jnp.where(
-                        de > 0, nu / jnp.maximum(de, 1e-9),
-                        g.astype(jnp.float32)).astype(g.dtype),
-                    global_params, num, den)
-            else:
-                part = jax.tree.map(
-                    lambda t: jnp.tensordot(a, t.astype(jnp.float32),
-                                            axes=1), p)
-                part = jax.lax.psum(part, "clients")
-                new_g = jax.tree.map(lambda g, t: t.astype(g.dtype),
-                                     global_params, part)
-            if comp != "none":
-                return (new_g, new_state, ratios, losses, new_err,
-                        coords) + ctrl_out
-            return (new_g, new_state, ratios, losses) + ctrl_out
+            with jax.named_scope("fl_local_train"):
+                p, new_state, ratios, losses, masks = jax.vmap(one_client)(
+                    cstate, batches, is_soft, *row_extra)
+            with jax.named_scope("fl_aggregate"):
+                ctrl_out = ()
+                if scheme.uses_control:
+                    # option-II control update from the RAW trained rows;
+                    # padding rows are masked out of the server fold by valid
+                    dc = jax.tree.map(
+                        lambda g, t, cg: (g.astype(jnp.float32)
+                                          - t.astype(jnp.float32)) * inv - cg,
+                        global_params, p, c_global)
+                    new_c_rows = jax.tree.map(lambda rr, d: rr + d, c_rows, dc)
+                    dc_sum = jax.tree.map(
+                        lambda d: jax.lax.psum(
+                            jnp.sum(d * valid.reshape((-1,) + (1,)
+                                                      * (d.ndim - 1)), axis=0),
+                            "clients"), dc)
+                    ctrl_out = (new_c_rows, dc_sum)
+                pm = adapter.expand_masks_batch(masks, global_params) \
+                    if (comp != "none" or agg_mode == "masked_mean") else None
+                if comp != "none":
+                    # codec runs shard-local on each device's cohort rows;
+                    # only the coordinate count crosses devices (one psum)
+                    delta = jax.tree.map(
+                        lambda t, g: t.astype(jnp.float32)
+                        - g.astype(jnp.float32), p, global_params)
+                    sent, new_err, coords = jax.vmap(
+                        lambda d, e, m: CP.compress_update(d, e, comp, frac,
+                                                           bits, m))(
+                            delta, err, pm)
+                    p = jax.tree.map(
+                        lambda g, s: (g.astype(jnp.float32)
+                                      + s).astype(g.dtype),
+                        global_params, sent)
+                    coords = jax.lax.psum(jnp.sum(coords * valid),
+                                          "clients")
+                base = ratios if agg_mode != "uniform" \
+                    else jnp.ones_like(ratios)
+                w = base * valid
+                a = w / jnp.maximum(jax.lax.psum(jnp.sum(w), "clients"), 1e-9)
+                if agg_mode == "masked_mean":
+                    pmasks = pm
+                    num = jax.tree.map(
+                        lambda m, t: jnp.sum(
+                            a.reshape((-1,) + (1,) * (t.ndim - 1)) * m
+                            * t.astype(jnp.float32), axis=0), pmasks, p)
+                    den = jax.tree.map(
+                        lambda m: jnp.sum(
+                            a.reshape((-1,) + (1,) * (m.ndim - 1)) * m,
+                            axis=0),
+                        pmasks)
+                    num, den = jax.lax.psum((num, den), "clients")
+                    new_g = jax.tree.map(
+                        lambda g, nu, de: jnp.where(
+                            de > 0, nu / jnp.maximum(de, 1e-9),
+                            g.astype(jnp.float32)).astype(g.dtype),
+                        global_params, num, den)
+                else:
+                    part = jax.tree.map(
+                        lambda t: jnp.tensordot(a, t.astype(jnp.float32),
+                                                axes=1), p)
+                    part = jax.lax.psum(part, "clients")
+                    new_g = jax.tree.map(lambda g, t: t.astype(g.dtype),
+                                         global_params, part)
+                if comp != "none":
+                    return (new_g, new_state, ratios, losses, new_err,
+                            coords) + ctrl_out
+                return (new_g, new_state, ratios, losses) + ctrl_out
 
         # check_vma=False: remat checkpoint_name (transformer stacks) has no
         # replication rule on current JAX; the psum above still leaves
@@ -1890,38 +1878,40 @@ class ShardedFLRun(BatchedFLRun):
 
     def _train_cohort(self, cohort: List[int], cclients: List[Client]):
         soft = self._scheme.soft_training
+        comp = self._comp_active()
         k, kpad = len(cohort), self._kpad
-        idx = np.asarray(cohort + [cohort[0]] * (kpad - k))
-        is_soft = jnp.asarray(
-            [1.0 if (soft and c.is_straggler) else 0.0
-             for c in cclients] + [0.0] * (kpad - k), jnp.float32)
-        valid = jnp.asarray([1.0] * k + [0.0] * (kpad - k), jnp.float32)
-        batches = self.adapter.sample_cohort(
-            self.rng, self.train_data, [c.data_idx for c in cclients],
-            self.local_steps, self.batch_size, pad_to=kpad)
-        cstate = ST.gather_states_host(self._pop_state, idx)
-        round_fn = self._get_sharded_fn()
-        extras = self._round_extras(cclients)
-        if not self._comp_active():
-            outs = round_fn(self.global_params, cstate, batches, is_soft,
-                            valid, *extras)
+        with self.rec.span("fl.sample"):
+            batches = self.adapter.sample_cohort(
+                self.rng, self.train_data, [c.data_idx for c in cclients],
+                self.local_steps, self.batch_size, pad_to=kpad)
+        with self.rec.span("fl.stack"):
+            idx = np.asarray(cohort + [cohort[0]] * (kpad - k))
+            is_soft = jnp.asarray(
+                [1.0 if (soft and c.is_straggler) else 0.0
+                 for c in cclients] + [0.0] * (kpad - k), jnp.float32)
+            valid = jnp.asarray([1.0] * k + [0.0] * (kpad - k), jnp.float32)
+            args = (self.global_params, ST.gather_states_host(
+                self._pop_state, idx), batches, is_soft, valid) \
+                + self._round_extras(cclients)
+            if comp:
+                args += (self._err_store.gather(
+                    [self.clients[i].cid for i in idx]),)
+            round_fn = self._get_sharded_fn()
+        with self.rec.span("fl.dispatch"):
+            outs = round_fn(*args)
+        # the host rows' write-back waits for the round program to end
+        with self.rec.span("fl.writeback"):
             self.global_params, new_cstate, ratios, losses = outs[:4]
-            self._apply_round_outs(cclients, outs[4:])
-        else:
-            err = self._err_store.gather(
-                [self.clients[i].cid for i in idx])
-            outs = round_fn(self.global_params, cstate, batches, is_soft,
-                            valid, *extras, err)
-            (self.global_params, new_cstate, ratios, losses, new_err,
-             coords) = outs[:6]
-            self.rec.accum("uplink_coords", coords)
-            self._err_store.scatter(
-                [self.clients[i].cid for i in cohort],
-                jax.tree.map(lambda x: x[:k], new_err))
-            self._apply_round_outs(cclients, outs[6:])
-        ST.scatter_states_host(
-            self._pop_state, cohort,
-            jax.tree.map(lambda x: x[:k], new_cstate))
+            if comp:
+                new_err, coords = outs[4:6]
+                self.rec.accum("uplink_coords", coords)
+                self._err_store.scatter(
+                    [self.clients[i].cid for i in cohort],
+                    jax.tree.map(lambda x: x[:k], new_err))
+            self._apply_round_outs(cclients, outs[6 if comp else 4:])
+            ST.scatter_states_host(
+                self._pop_state, cohort,
+                jax.tree.map(lambda x: x[:k], new_cstate))
         # device slices on purpose — _record_round converts behind the gate
         return losses[:k], ratios[:k]
 

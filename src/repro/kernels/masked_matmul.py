@@ -60,7 +60,7 @@ def _kernel(alive_ref, x_ref, w_ref, o_ref, acc_ref, *, n_k: int,
 
 
 def _call(x, w, block_alive, alive_axis, block_m, block_n, block_k,
-          interpret):
+          interpret, name):
     m, k = x.shape
     k2, n = w.shape
     assert k == k2, (x.shape, w.shape)
@@ -85,23 +85,27 @@ def _call(x, w, block_alive, alive_axis, block_m, block_n, block_k,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         interpret=interpret,
+        name=name,
     )(block_alive.astype(jnp.int32), x, w)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("block_m", "block_n", "block_k",
-                                    "interpret"))
+                                    "interpret", "name"))
 def masked_matmul(x: jax.Array, w: jax.Array, block_alive: jax.Array,
                   *, block_m: int = 128, block_n: int = 128,
-                  block_k: int = 128, interpret: bool = False) -> jax.Array:
+                  block_k: int = 128, interpret: bool = False,
+                  name: str = "masked_matmul") -> jax.Array:
     """y = x @ w with dead column-blocks skipped.
 
     x: (M, K); w: (K, N); block_alive: (N // block_n,) int32/bool.
     Masked-out columns of the result are ZERO (matching W*mask semantics
-    when the mask is block-aligned).
+    when the mask is block-aligned).  ``name`` names the kernel call in the
+    compiled program and the device trace (it must contain
+    ``masked_matmul``).
     """
     return _call(x, w, block_alive, 1, block_m, block_n, block_k,
-                 interpret)
+                 interpret, name)
 
 
 @functools.partial(jax.jit,
@@ -118,4 +122,4 @@ def masked_matmul_dk(x: jax.Array, w: jax.Array, block_alive: jax.Array,
     masked hidden activations h·mask).
     """
     return _call(x, w, block_alive, 2, block_m, block_n, block_k,
-                 interpret)
+                 interpret, "masked_matmul_dk")

@@ -112,8 +112,9 @@ def _block_alive(unit_mask: jax.Array, block_n: int) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
-def _mm_padded(x, w, unit_mask, block_n):
-    """Column-masked kernel over padded operands; exact ``x @ (w·mask)``."""
+def _mm_padded(x, w, unit_mask, block_n, name="masked_matmul_fwd"):
+    """Column-masked kernel over padded operands; exact ``x @ (w·mask)``.
+    ``name`` tells the forward call from the backward one in a trace."""
     m, k = x.shape
     n = w.shape[1]
     bm, bk = _free_block(m), _free_block(k)
@@ -121,7 +122,7 @@ def _mm_padded(x, w, unit_mask, block_n):
     wp = _pad_axis(_pad_axis(w, 0, bk), 1, block_n)
     alive = _block_alive(unit_mask, block_n)
     y = _mm(xp, wp, alive, block_m=bm, block_n=block_n, block_k=bk,
-            interpret=_interpret())[:m, :n]
+            interpret=_interpret(), name=name)[:m, :n]
     # multiply by the unit mask: restores exactness for masks that are not
     # block-constant (a live block may still contain dead units) and pins
     # dead columns to bit-zero even on the padded path
@@ -163,7 +164,7 @@ def _masked_dense_pallas(block_n: int):
         x, w, unit_mask = res
         dym = dy * unit_mask.astype(dy.dtype)[None, :]
         dx = _mm_dk_padded(dym, w.T, unit_mask, block_n)
-        dw = _mm_padded(x.T, dym, unit_mask, block_n)
+        dw = _mm_padded(x.T, dym, unit_mask, block_n, "masked_matmul_bwd")
         return dx, dw, jnp.zeros_like(unit_mask)
 
     fn.defvjp(fwd, bwd)
@@ -192,10 +193,11 @@ def _masked_contract_pallas(block_n: int):
     def bwd(res, dy):
         h, w, unit_mask = res
         # dh = dy @ wᵀ, masked columns (dh's N axis = the masked dim) zeroed
-        dh = _mm_padded(dy, w.T, unit_mask, block_n)
+        dh = _mm_padded(dy, w.T, unit_mask, block_n, "masked_matmul_bwd")
         # dw = hᵀ @ dy, rows = masked dim: compute dwᵀ with the column-masked
         # kernel, so dead rows of dw are skipped AND exactly zero
-        dw = _mm_padded(dy.T, h, unit_mask, block_n).T
+        dw = _mm_padded(dy.T, h, unit_mask, block_n,
+                        "masked_matmul_bwd").T
         return dh, dw, jnp.zeros_like(unit_mask)
 
     fn.defvjp(fwd, bwd)

@@ -3,38 +3,56 @@
 Two layers, mirroring the ``REPRO_CONTRACTS`` arming pattern
 (repro.analysis.contracts):
 
-* **Accounting** (always on): counters, gauges, and device-scalar
-  accumulators.  These ARE the engines' runtime bookkeeping —
-  ``events_processed``, ``agg_counter``, ``uplink_coords``, … live here
-  and the old engine attributes are thin property views.  Counter writes
-  are plain dict arithmetic on host ints; ``accum`` adds device scalars
-  eagerly WITHOUT syncing (the uplink-coords pattern: the value crosses
-  to host exactly once, in :meth:`accum_value`, behind an
+* **Accounting** (always on): counters, gauges, device-scalar
+  accumulators, and the span ring.  These ARE the engines' runtime
+  bookkeeping — ``events_processed``, ``agg_counter``, ``uplink_coords``,
+  … live here and the old engine attributes are thin property views.
+  Counter writes are plain dict arithmetic on host ints; ``accum`` adds
+  device scalars eagerly WITHOUT syncing (the uplink-coords pattern: the
+  value crosses to host exactly once, in :meth:`accum_value`, behind an
   ``expected_transfer``), so a disarmed recorder changes neither the
   engines' trajectories nor their host-transfer profile.
-* **Emission** (armed only): dual-clock spans, histogram observations,
-  and the JSONL event stream + run manifest sinks.  Armed via
+* **Emission** (armed only): the JSONL event stream (span, round,
+  histogram and engine events) + run manifest sinks.  Armed via
   ``REPRO_OBS=on``, a session :func:`repro.obs.override`, or an explicit
   ``Recorder(armed=True)``.  Disarmed, every emission method is one
   boolean test and zero events are ever buffered or written.
 
+**The span ring.**  Every span — :meth:`Recorder.span`, or the
+module-level :func:`span` for code that holds no recorder — is kept,
+armed or not, in one bounded in-process ring (the last ``RING_SIZE``
+spans): its name, start and end in ``time.time_ns()``, the round it
+belongs to and its enclosing span.  ``time.time_ns()`` is the host clock
+the JAX profiler stamps its events on, and a trace's ``Task Environment``
+plane records ``profile_start_time`` on it, so ``start_ns -
+profile_start_time`` places a span on a trace's timeline exactly.  The
+ring takes no lock and does no I/O, and a span never touches a device
+value, so spans are legal inside ``CT.no_host_transfers``.  Any caller
+in the process reads it with :func:`recent_spans` — after a slow or
+stalled round, the phases of the last rounds and how long each took.
+
 Every event carries the **dual clock**: ``sim`` is the caller-supplied
 simulated time (the engines' SimClock / round clock — deterministic, so
 fixed-seed event streams are engine-comparable) and ``wall`` is host
-``time.perf_counter`` relative to recorder construction (real, so spans
-price what instrumentation and training actually cost).  Determinism
-tests compare :meth:`sim_events` (wall fields stripped); profiling reads
-the wall side.
+``time.time_ns()`` in seconds since recorder construction (real, so
+spans price what instrumentation and training actually cost).  A span
+with a ``sim`` time is part of the simulated round protocol and emits a
+``span`` event; one without (an engine's own phases: stacking, write-back)
+emits a ``phase`` event, which is not a sim kind.  Determinism tests
+compare :meth:`sim_events` (wall fields stripped); profiling reads the
+wall side.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import json
 import os
 import subprocess
 import threading
 import time
-from typing import Dict, List, Optional
+import warnings
+from typing import Dict, List, NamedTuple, Optional
 
 import jax
 
@@ -92,7 +110,74 @@ def git_sha() -> str:
         return "unknown"
 
 
-_NULL_CTX = contextlib.nullcontext()
+#: spans the ring keeps: some hundreds of rounds at ten spans per round
+RING_SIZE = 4096
+
+_RING: collections.deque = collections.deque(maxlen=RING_SIZE)
+
+
+class Span(NamedTuple):
+    """One finished span: ``start_ns``/``end_ns`` on ``time.time_ns()``;
+    ``round`` is the round it belongs to (its own tag, else its enclosing
+    span's), ``parent`` the enclosing span's name."""
+    name: str
+    start_ns: int
+    end_ns: int
+    round: Optional[int]
+    parent: Optional[str]
+
+
+def recent_spans() -> List[Span]:
+    """The spans in the ring, in the order they ended (oldest first)."""
+    return [Span(*s) for s in list(_RING)]
+
+
+def _open_spans() -> list:
+    try:
+        return _TLS.spans
+    except AttributeError:
+        _TLS.spans = []
+        return _TLS.spans
+
+
+class _RingSpan:
+    """Context manager behind :func:`span` and :meth:`Recorder.span`: on
+    exit, one tuple into the ring, and the JSONL event if ``rec`` is an
+    armed recorder."""
+    __slots__ = ("name", "round", "parent", "t0", "rec", "sim", "tags")
+
+    def __init__(self, name, rnd=None, rec=None, sim=None, tags=None):
+        self.name, self.round, self.rec = name, rnd, rec
+        self.sim, self.tags = sim, tags
+
+    def __enter__(self):
+        stack = _open_spans()
+        self.parent = stack[-1] if stack else None
+        if self.round is None and self.parent is not None:
+            self.round = self.parent.round
+        stack.append(self)
+        self.t0 = time.time_ns()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.time_ns()
+        _open_spans().pop()
+        _RING.append((self.name, self.t0, t1, self.round,
+                      None if self.parent is None else self.parent.name))
+        if self.rec is not None:
+            fields = dict(self.tags)
+            if self.round is not None:
+                fields.setdefault("round", self.round)
+            self.rec.event("span" if self.sim is not None else "phase",
+                           sim=self.sim, name=self.name,
+                           wall_ms=(t1 - self.t0) * 1e-6, **fields)
+        return False
+
+
+def span(name: str, round: Optional[int] = None) -> _RingSpan:
+    """A span into the ring only, for code that holds no recorder (its
+    round is its enclosing span's unless given)."""
+    return _RingSpan(name, round)
 
 
 class Recorder:
@@ -114,7 +199,7 @@ class Recorder:
         self.hists: Dict[str, List[float]] = {}
         self.events: List[dict] = []
         self._accums: Dict[str, jax.Array] = {}
-        self._t0 = time.perf_counter()
+        self._t0_ns = time.time_ns()
         self.profile_round = env_profile_round() \
             if profile_round is None else profile_round
         self.profile_dir = profile_dir
@@ -163,7 +248,7 @@ class Recorder:
         if not self.armed:
             return
         ev: dict = {"kind": kind,
-                    "wall": time.perf_counter() - self._t0}
+                    "wall": (time.time_ns() - self._t0_ns) * 1e-9}
         if sim is not None:
             ev["sim"] = sim
         ev.update(fields)
@@ -176,46 +261,33 @@ class Recorder:
         self.hists.setdefault(name, []).append(value)
 
     def span(self, name: str, sim: Optional[float] = None, **tags):
-        """Dual-clock span: emits one ``span`` event carrying the
-        caller's sim time and the measured wall duration."""
-        if not self.armed:
-            return _NULL_CTX
-        return self._span(name, sim, tags)
-
-    @contextlib.contextmanager
-    def _span(self, name, sim, tags):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.event("span", sim=sim, name=name,
-                       wall_ms=(time.perf_counter() - t0) * 1e3, **tags)
+        """A span into the ring (always); armed, also one ``span`` event
+        (with a ``sim`` time) or ``phase`` event (without) carrying the
+        measured wall duration and the tags."""
+        return _RingSpan(name, tags.get("round"),
+                         self if self.armed else None, sim, tags)
 
     @contextlib.contextmanager
     def maybe_profile(self, round_idx: int):
-        """Capture a ``jax.profiler`` trace around ONE chosen round
-        (armed + ``profile_round`` match); otherwise free."""
+        """Capture a ``jax.profiler`` trace of ONE chosen round into
+        ``profile_dir`` (armed + ``profile_round`` match); otherwise free.
+        A backend that cannot profile gets a warning, not an error."""
         if not self.armed or self.profile_round is None or \
                 round_idx != self.profile_round:
             yield
             return
-        started = False
         try:
             jax.profiler.start_trace(self.profile_dir)
-            started = True
         except Exception as e:               # backend without profiling
-            self.event("profile_error", round=round_idx, error=str(e))
+            warnings.warn(f"repro.obs: round {round_idx} not profiled: {e}")
+            started = False
+        else:
+            started = True
         try:
             yield
         finally:
             if started:
-                try:
-                    jax.profiler.stop_trace()
-                    self.event("profile_trace", round=round_idx,
-                               dir=self.profile_dir)
-                except Exception as e:
-                    self.event("profile_error", round=round_idx,
-                               error=str(e))
+                jax.profiler.stop_trace()
 
     # -- views / sinks --------------------------------------------------
     def sim_events(self, kinds=SIM_KINDS) -> List[dict]:

@@ -176,7 +176,7 @@ def render(events: List[dict]) -> str:
                 parts.append(f"{name}: " + json.dumps(hists[name],
                                                       sort_keys=True))
 
-    spans = _by_kind(events, "span")
+    spans = _by_kind(events, "span") + _by_kind(events, "phase")
     if spans:
         agg = {}
         for s in spans:

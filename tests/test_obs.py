@@ -36,6 +36,7 @@ import importlib.util
 import json
 import subprocess
 import sys
+import time
 
 import jax
 import jax.numpy as jnp
@@ -125,6 +126,132 @@ def test_armed_recorder_flush_roundtrip(tmp_path):
     assert [e["kind"] for e in sims] == ["round", "span"]
     assert all("wall" not in e and "wall_ms" not in e for e in sims)
     assert out["summary"]["hists"]["staleness"]["count"] == 1
+
+
+@pytest.mark.parametrize("armed", [False, True], ids=["disarmed", "armed"])
+def test_span_ring_keeps_every_span_bounded(armed):
+    """Spans go to the ring armed or not: name, time_ns start and end, the
+    round (inherited from the enclosing span) and the parent's name.  Only
+    an armed recorder emits events: ``span`` with a sim time, ``phase``
+    without (not a sim kind)."""
+    rec = OBS.Recorder(armed=armed)
+    t0 = time.time_ns()
+    with rec.span("outer", sim=1.0, round=3):
+        with rec.span("inner"):
+            with OBS.span("leaf"):
+                pass
+    t1 = time.time_ns()
+    leaf, inner, outer = OBS.recent_spans()[-3:]
+    assert [s.name for s in (leaf, inner, outer)] == ["leaf", "inner",
+                                                      "outer"]
+    assert [(s.round, s.parent) for s in (leaf, inner, outer)] == [
+        (3, "inner"), (3, "outer"), (3, None)]
+    assert t0 <= outer.start_ns <= inner.start_ns <= leaf.start_ns \
+        <= leaf.end_ns <= inner.end_ns <= outer.end_ns <= t1
+    if armed:
+        assert [(e["kind"], e["name"], e["round"]) for e in rec.events] \
+            == [("phase", "inner", 3), ("span", "outer", 3)]
+        assert [e["name"] for e in rec.sim_events()] == ["outer"]
+    else:
+        assert rec.events == []
+    for i in range(OBS.RING_SIZE + 10):
+        with OBS.span("fill", round=i):
+            pass
+    ring = OBS.recent_spans()
+    assert len(ring) == OBS.RING_SIZE
+    assert ring[-1].round == OBS.RING_SIZE + 9
+    assert ring[0].round == 10
+
+
+#: each sync round's phases, children of its ``fl.round`` span, in order
+PHASES = ["fl.sample", "fl.stack", "fl.dispatch", "fl.writeback",
+          "fl.adapt", "fl.evaluate"]
+
+
+@pytest.mark.parametrize("cls", [BatchedFLRun, ShardedFLRun],
+                         ids=["batched", "sharded"])
+def test_sync_round_phases_in_the_ring(setting, cls):
+    with OBS.override(False):
+        run = _make(setting, cls)
+        t0 = time.time_ns()
+        run.run_sync(ROUNDS)
+    spans = [s for s in OBS.recent_spans() if s.start_ns >= t0]
+    for r in range(ROUNDS):
+        (rnd,) = [s for s in spans if s.name == "fl.round" and s.round == r]
+        kids = sorted((s for s in spans
+                       if s.parent == "fl.round" and s.round == r),
+                      key=lambda s: s.start_ns)
+        assert [s.name for s in kids] == PHASES
+        assert rnd.start_ns <= kids[0].start_ns
+        assert kids[-1].end_ns <= rnd.end_ns
+        for a, b in zip(kids, kids[1:]):         # siblings never overlap
+            assert a.end_ns <= b.start_ns
+    nested = [s for s in spans if s.parent == "fl.sample"]
+    # the sharded sampler stacks the cohort itself: fl.stack nests there
+    if cls is ShardedFLRun:
+        assert {(s.name, s.round) for s in nested} \
+            == {("fl.stack", r) for r in range(ROUNDS)}
+    else:
+        assert nested == []
+
+
+#: the round programs' named scopes (a trace's ``tf_op`` paths)
+SCOPES = {"batched": ("_get_round_fn", ["fl_straggler_train",
+                                        "fl_capable_train", "fl_aggregate"]),
+          "sharded": ("_get_sharded_fn", ["fl_local_train", "fl_aggregate"])}
+
+
+@pytest.mark.parametrize("cls", [BatchedFLRun, ShardedFLRun],
+                         ids=["batched", "sharded"])
+def test_round_program_carries_named_scopes(setting, cls):
+    getter, scopes = SCOPES["sharded" if cls is ShardedFLRun else "batched"]
+    with OBS.override(False):
+        run = _make(setting, cls)
+    texts = []
+    get = getattr(run, getter)
+
+    def spy(*args):
+        fn = get(*args)
+
+        def call(*xs):
+            texts.append(fn.lower(*xs).as_text(debug_info=True))
+            return fn(*xs)
+        return call
+
+    setattr(run, getter, spy)
+    with OBS.override(False):
+        run.run_sync(1)
+    (text,) = texts
+    for scope in scopes:
+        assert f"/{scope}/" in text, scope
+
+
+def test_masked_matmul_kernels_carry_their_names():
+    from repro.kernels import ops
+
+    def loss(x, w, m):
+        h = ops.masked_dense(x, w, m, impl=ops.PALLAS, block_n=128)
+        return jnp.sum(ops.masked_contract(h, w, m, impl=ops.PALLAS,
+                                           block_n=128) ** 2)
+
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1)))(
+        jnp.ones((8, 256)), jnp.ones((256, 256)), jnp.ones((256,)))
+
+    def eqns(jx):
+        for e in jx.eqns:
+            yield e
+            for p in e.params.values():
+                for sub in (p if isinstance(p, (list, tuple)) else [p]):
+                    inner = getattr(sub, "jaxpr", sub)
+                    if hasattr(inner, "eqns"):
+                        yield from eqns(inner)
+
+    names = [str(e.params["name"]) for e in eqns(jaxpr.jaxpr)
+             if e.primitive.name == "pallas_call"]
+    # forward (dense fwd, contract fwd), then dense dx/dw and contract dh/dw
+    assert sorted(names) == sorted(
+        ["masked_matmul_fwd", "masked_matmul_dk", "masked_matmul_dk"]
+        + ["masked_matmul_bwd"] * 3)
 
 
 # ---------------------------------------------------------------------------
