@@ -27,11 +27,13 @@ Python branching on traced values.
 """
 from __future__ import annotations
 
-from typing import Dict
+from typing import Dict, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.core import contribution as C
@@ -42,6 +44,54 @@ from repro.obs import recorder as OBS
 
 #: model families whose batch is a plain token stream {"tokens": (B, S)}
 TOKEN_FAMILIES = ("dense", "moe", "ssm", "hybrid")
+
+
+def fits_on_device(nbytes: int, bytes_limit: Optional[int]) -> bool:
+    """The resident-data rule: a training set of ``nbytes`` stays on a
+    device when it takes at most a quarter of the device's memory
+    (``bytes_limit``).  A backend that reports no limit (the CPU) counts
+    as fitting."""
+    return bytes_limit is None or nbytes <= bytes_limit // 4
+
+
+def device_bytes_limit(devices) -> Optional[int]:
+    """The smallest ``memory_stats()["bytes_limit"]`` among ``devices``, or
+    None where the backend reports none."""
+    limits = [s["bytes_limit"] for s in (d.memory_stats() for d in devices)
+              if s and "bytes_limit" in s]
+    return min(limits) if limits else None
+
+
+class DeviceData:
+    """A training set resident on the device: every leaf whole on the
+    default device, or on each device of ``mesh``, with its host dtype.
+
+    :meth:`gather` turns host-drawn example indices into stacked batches
+    with one jitted gather program, so a round moves only the indices from
+    the host, and the gather reads and writes at the device's memory speed.
+    """
+
+    def __init__(self, data: Dict[str, np.ndarray],
+                 mesh: Optional[Mesh] = None):
+        self.mesh = mesh
+        where = NamedSharding(mesh, P()) if mesh is not None else None
+        self.arrays = {k: jax.device_put(v, where) for k, v in data.items()}
+        #: bytes of the copy on each device
+        self.nbytes = sum(int(v.nbytes) for v in self.arrays.values())
+        # this copy's own program cache: one program per index shapes
+        self._gather = jax.jit(lambda arrays, takes: tuple(
+            {k: v[t] for k, v in arrays.items()} for t in takes))
+
+    def gather(self, parts: Sequence[np.ndarray]) -> tuple:
+        """Stacked batches for each array of example indices in ``parts``
+        (rows, steps, batch), in one transfer of the indices and one call
+        of the gather program.  On a mesh the rows are split over its
+        ``clients`` axis, so each device gathers its own rows from its own
+        copy and nothing moves between devices."""
+        rows = None if self.mesh is None else NamedSharding(self.mesh,
+                                                            P("clients"))
+        idx = jax.device_put(tuple(p.astype(np.int32) for p in parts), rows)
+        return self._gather(self.arrays, idx)
 
 
 class FamilyAdapter:
@@ -69,26 +119,32 @@ class FamilyAdapter:
     def num_examples(self, data: Dict[str, np.ndarray]) -> int:
         return len(next(iter(data.values())))
 
-    def sample_batch(self, rng: np.random.Generator,
-                     data: Dict[str, np.ndarray], idx: np.ndarray,
-                     local_steps: int, batch_size: int) -> dict:
-        """Draw a (local_steps, batch_size)-leading batch dict from one
-        client's example indices, consuming the host RNG exactly once (the
-        batched engine replays the sequential engine's draw order).
+    def draw_indices(self, rng: np.random.Generator, idx,
+                     local_steps: int, batch_size: int) -> np.ndarray:
+        """One client's (local_steps, batch_size) example indices, drawn
+        from its index set ``idx`` with ONE host-RNG call (every engine
+        replays the sequential engine's draw order).
 
         ``idx`` may be any array-like — in particular a lazy partition view
         (data.federated.LazyParts), which only materializes indices for the
         clients actually sampled into a round's cohort.
         """
         idx = np.asarray(idx)
-        take = rng.choice(idx, size=(local_steps, batch_size),
+        return rng.choice(idx, size=(local_steps, batch_size),
                           replace=len(idx) < local_steps * batch_size)
+
+    def sample_batch(self, rng: np.random.Generator,
+                     data: Dict[str, np.ndarray], idx: np.ndarray,
+                     local_steps: int, batch_size: int) -> dict:
+        """Draw a (local_steps, batch_size)-leading batch dict from one
+        client's example indices (:meth:`draw_indices`), looked up in the
+        host arrays."""
+        take = self.draw_indices(rng, idx, local_steps, batch_size)
         return {k: jnp.asarray(v[take]) for k, v in data.items()}
 
-    def sample_cohort(self, rng: np.random.Generator,
-                      data: Dict[str, np.ndarray], idx_seq,
-                      local_steps: int, batch_size: int,
-                      pad_to: int = 0) -> dict:
+    def sample_cohort(self, rng: np.random.Generator, data, idx_seq,
+                      local_steps: int, batch_size: int, pad_to: int = 0,
+                      groups: Optional[Sequence[Sequence[int]]] = None):
         """Per-client batches drawn in cohort order, stacked along a leading
         client axis.
 
@@ -97,14 +153,29 @@ class FamilyAdapter:
         engine) replicate the first client's draw WITHOUT consuming the
         host RNG, so the padded engines stay draw-for-draw equivalent to
         the sequential references; engines give padding slots zero
-        aggregation/mixing weight.
+        aggregation/mixing weight.  ``groups`` (lists of cohort positions)
+        returns one stacked batch dict per group instead, None for an empty
+        group.
+
+        Only the indices are drawn and stacked on the host.  ``data`` is
+        the host arrays, where they are looked up with numpy, or a
+        :class:`DeviceData` copy resident on the device, where one gather
+        program looks them up: both give the same arrays for the same draws.
         """
-        per = [self.sample_batch(rng, data, idx, local_steps, batch_size)
-               for idx in idx_seq]
-        if pad_to and pad_to > len(per):
-            per = per + [per[0]] * (pad_to - len(per))
+        takes = [self.draw_indices(rng, idx, local_steps, batch_size)
+                 for idx in idx_seq]
+        takes = np.stack(takes + [takes[0]] * (pad_to - len(takes)))
+        parts = [takes] if groups is None else \
+            [takes[list(g)] for g in groups if g]
         with OBS.span("fl.stack"):
-            return jax.tree.map(lambda *xs: jnp.stack(xs), *per)
+            if isinstance(data, DeviceData):
+                out = iter(data.gather(parts))
+            else:
+                out = iter([{k: jnp.asarray(v[t]) for k, v in data.items()}
+                            for t in parts])
+        if groups is None:
+            return next(out)
+        return tuple(next(out) if g else None for g in groups)
 
     def eval_slice(self, data: Dict[str, np.ndarray], lo: int,
                    hi: int) -> dict:

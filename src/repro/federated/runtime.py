@@ -61,7 +61,9 @@ from repro.core import soft_train as ST
 from repro.core import volume as VOL
 from repro.core.identification import (DeviceProfile, identify_resource_based,
                                        identify_time_based)
-from repro.federated.adapter import FamilyAdapter, make_adapter
+from repro.federated.adapter import (DeviceData, FamilyAdapter,
+                                     device_bytes_limit, fits_on_device,
+                                     make_adapter)
 from repro.federated.events import (ArrivalProcess, DropoutProcess, Event,
                                     SimClock)
 from repro.federated.heterogeneity import cycle_time
@@ -980,6 +982,44 @@ class AsyncFLRun(FLRun):
     #: cap on events per bucket (bounds the vmapped program's memory)
     max_bucket: int = 128
 
+    #: what cohorts are sampled from (:meth:`_place_train_data`), and the
+    #: mesh it was placed for
+    _cohort_data = None
+    _cohort_mesh = None
+
+    def _place_train_data(self, mesh: Optional[Mesh]) -> None:
+        """Keep the training set on the device (on every device of
+        ``mesh``) when it fits there (:func:`fits_on_device`), so that each
+        cohort's batches are gathered on the device from host-drawn indices;
+        otherwise the batches are looked up in the host arrays, as the
+        sequential engine does.  Decided once per mesh."""
+        if self._cohort_data is not None and self._cohort_mesh == mesh:
+            return
+        self._cohort_mesh = mesh
+        devices = jax.devices()[:1] if mesh is None else mesh.devices.flat
+        nbytes = sum(np.asarray(v).nbytes for v in self.train_data.values())
+        if fits_on_device(nbytes, device_bytes_limit(devices)):
+            self._cohort_data = DeviceData(self.train_data, mesh)
+            self.rec.gauge("resident_train_bytes", self._cohort_data.nbytes)
+        else:
+            self._cohort_data = self.train_data
+            self.rec.gauge("resident_train_bytes", 0)
+
+    def _cohort_batches(self, clients: Sequence[Client],
+                        mesh: Optional[Mesh] = None, **kw):
+        """The cohort's stacked batches through the adapter's sampler,
+        drawn from ``self.rng`` in ``clients`` order, rows split over
+        ``mesh`` where given; places the training set on the first call
+        (:meth:`_place_train_data`) and counts the cohorts gathered on the
+        device and those looked up on the host."""
+        self._place_train_data(mesh)
+        on_device = isinstance(self._cohort_data, DeviceData)
+        self.rec.inc("sample_device_cohorts" if on_device
+                     else "sample_host_cohorts")
+        return self.adapter.sample_cohort(
+            self.rng, self._cohort_data, [c.data_idx for c in clients],
+            self.local_steps, self.batch_size, **kw)
+
     def _make_bucket_fn(self, bpad: int):
         adapter, opt = self.adapter, self.opt
         ones_masks = ST.full_masks(adapter.schema)
@@ -1136,10 +1176,8 @@ class AsyncFLRun(FLRun):
                 # per-event batch draws in pop order — bit-identical rng
                 # consumption to the sequential loop; padding replicates
                 # slot 0 without touching the stream (PR 3's cohort seam)
-                batches = self.adapter.sample_cohort(
-                    self.rng, self.train_data,
-                    [by_id[ev.cid].data_idx for ev in exec_evs],
-                    self.local_steps, self.batch_size, pad_to=bpad)
+                batches = self._cohort_batches(
+                    [by_id[ev.cid] for ev in exec_evs], pad_to=bpad)
                 agg0 = self.agg_counter
                 base_slots, write_slots, stales = [], [], []
                 fresh_read, fresh_write, is_fresh = [], [], []
@@ -1503,14 +1541,9 @@ class BatchedFLRun(AsyncFLRun):
         else:
             s_pos, c_pos = self._s_idx, self._c_idx
         with self.rec.span("fl.sample"):
-            per = [self._sample_batches(c) for c in cclients]
+            s_batch, c_batch = self._cohort_batches(cclients,
+                                                    groups=(s_pos, c_pos))
         with self.rec.span("fl.stack"):
-            def stack(pos):
-                if not pos:
-                    return None
-                return jax.tree.map(lambda *xs: jnp.stack(xs),
-                                    *[per[j] for j in pos])
-
             if self.participation:
                 unperm = jnp.asarray(np.argsort(np.asarray(s_pos + c_pos)),
                                      jnp.int32)
@@ -1518,7 +1551,7 @@ class BatchedFLRun(AsyncFLRun):
                                           for j in s_pos]) if s_pos else None
             else:
                 unperm, sstate = self._unperm, self._sstate
-            args = (self.global_params, sstate, stack(s_pos), stack(c_pos),
+            args = (self.global_params, sstate, s_batch, c_batch,
                     unperm) + self._round_extras(cclients)
             # stacked rows are in cohort order (cat() un-permutes), so the
             # error rows gather/scatter in that same order
@@ -1881,9 +1914,8 @@ class ShardedFLRun(BatchedFLRun):
         comp = self._comp_active()
         k, kpad = len(cohort), self._kpad
         with self.rec.span("fl.sample"):
-            batches = self.adapter.sample_cohort(
-                self.rng, self.train_data, [c.data_idx for c in cclients],
-                self.local_steps, self.batch_size, pad_to=kpad)
+            batches = self._cohort_batches(cclients, self._mesh,
+                                           pad_to=kpad)
         with self.rec.span("fl.stack"):
             idx = np.asarray(cohort + [cohort[0]] * (kpad - k))
             is_soft = jnp.asarray(

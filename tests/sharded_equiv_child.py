@@ -4,9 +4,14 @@ Run by tests/test_sharded_engine.py in a SUBPROCESS (own XLA_FLAGS, like
 tests/test_dryrun_small.py) so the forced host-device count never disturbs
 the parent's single-device jax.  Runs all three engines — sequential,
 batched, and client-sharded — on the same fixed-seed setting and prints one
-JSON line per scheme with the pairwise max param diffs.
+JSON line per scheme with the pairwise max param diffs, and the sharded
+engine's diff against its own run on the host sampling path.  ``--gather``
+instead compiles the sharded engine's cohort gather on a 4-device client
+mesh and prints one JSON line: the collectives in the compiled program, the
+batches' sharding, and whether they equal numpy indexing.
 
   REPRO_HOST_DEVICES=16 python tests/sharded_equiv_child.py --family cnn
+  REPRO_HOST_DEVICES=16 python tests/sharded_equiv_child.py --gather
 """
 import os
 
@@ -23,8 +28,15 @@ import numpy as np
 from repro.configs import ARCHS, CNNS, HeliosConfig, reduced
 from repro.data.federated import partition_by_topic, partition_noniid
 from repro.data.synthetic import class_gaussian_images, markov_topic_tokens
+import repro.federated.runtime as RT
 from repro.federated import (BatchedFLRun, FLRun, ShardedFLRun, make_fleet,
                              setup_clients)
+from repro.federated.adapter import DeviceData
+from repro.launch.mesh import make_client_mesh
+
+#: what a cross-device gather would compile to
+COLLECTIVES = ("all-gather", "all-to-all", "collective-permute",
+               "all-reduce")
 
 
 def _max_param_diff(a, b):
@@ -50,26 +62,56 @@ def _setting(family: str):
     return cfg, {"tokens": tokens}, {"tokens": test_tokens}, parts
 
 
+def gather_check() -> dict:
+    """The sharded gather on a 4-device client mesh: rows split over the
+    ``clients`` axis, each device reading its own copy."""
+    _, train, _, _ = _setting("cnn")
+    data = DeviceData(train, make_client_mesh(4))
+    takes = np.random.default_rng(0).integers(
+        0, len(train["labels"]), size=(8, 2, 32))
+    (out,) = data.gather([takes])
+    idx = jax.device_put((takes.astype(np.int32),), jax.sharding.NamedSharding(
+        data.mesh, jax.sharding.PartitionSpec("clients")))
+    hlo = data._gather.lower(data.arrays, idx).compile().as_text()
+    return {
+        "n_devices": len(jax.devices()),
+        "mesh_shards": int(data.mesh.devices.size),
+        "collectives": [c for c in COLLECTIVES if c in hlo],
+        "specs": sorted({str(v.sharding.spec) for v in out.values()}),
+        "equal": all(np.array_equal(np.asarray(out[k]), v[takes])
+                     for k, v in train.items()),
+    }
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--family", choices=["cnn", "lm"], default="cnn")
     ap.add_argument("--schemes", default="helios,syn,st_only")
     ap.add_argument("--rounds", type=int, default=3)
+    ap.add_argument("--gather", action="store_true")
     args = ap.parse_args()
+    if args.gather:
+        print("GATHER " + json.dumps(gather_check()), flush=True)
+        return
 
     cfg, train, test, parts = _setting(args.family)
+    fits = RT.fits_on_device
     for scheme in args.schemes.split(","):
         engines = {}
         hists = {}
         for name, cls in (("seq", FLRun), ("bat", BatchedFLRun),
-                          ("shd", ShardedFLRun)):
+                          ("shd", ShardedFLRun), ("shd_host", ShardedFLRun)):
             hcfg = HeliosConfig()
             clients = setup_clients(make_fleet(2, 2), parts, hcfg)
+            # shd_host: the same engine, told that no training set fits
+            RT.fits_on_device = fits if name != "shd_host" \
+                else (lambda nbytes, limit: False)
             run = cls(cfg, hcfg, scheme, clients, train, test,
                       local_steps=2, batch_size=4 if args.family == "lm"
                       else 32, lr=0.1, seed=0, eval_batch=64)
             hists[name] = run.run_sync(args.rounds)
             engines[name] = run
+        RT.fits_on_device = fits
         rec = {
             "family": args.family, "scheme": scheme,
             "n_devices": len(jax.devices()),
@@ -80,6 +122,13 @@ def main():
                                             engines["shd"].global_params),
             "diff_bat_shd": _max_param_diff(engines["bat"].global_params,
                                             engines["shd"].global_params),
+            "diff_shd_host": _max_param_diff(
+                engines["shd"].global_params,
+                engines["shd_host"].global_params),
+            "device_cohorts": engines["shd"].rec.count(
+                "sample_device_cohorts"),
+            "host_cohorts": engines["shd_host"].rec.count(
+                "sample_host_cohorts"),
             "ratios_equal": all(
                 np.allclose(a["ratios"], b["ratios"], atol=1e-6)
                 for a, b in zip(hists["seq"], hists["shd"])),

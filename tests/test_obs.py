@@ -187,12 +187,10 @@ def test_sync_round_phases_in_the_ring(setting, cls):
         for a, b in zip(kids, kids[1:]):         # siblings never overlap
             assert a.end_ns <= b.start_ns
     nested = [s for s in spans if s.parent == "fl.sample"]
-    # the sharded sampler stacks the cohort itself: fl.stack nests there
-    if cls is ShardedFLRun:
-        assert {(s.name, s.round) for s in nested} \
-            == {("fl.stack", r) for r in range(ROUNDS)}
-    else:
-        assert nested == []
+    # both engines' samplers build the cohort's batches themselves (the
+    # gather on the device, or the stack on the host): fl.stack nests there
+    assert {(s.name, s.round) for s in nested} \
+        == {("fl.stack", r) for r in range(ROUNDS)}
 
 
 #: the round programs' named scopes (a trace's ``tf_op`` paths)

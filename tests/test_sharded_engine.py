@@ -132,20 +132,38 @@ def test_sharded_population_state_roundtrip(setting):
             assert int(c.helios_state["cycle"]) == 0
 
 
-def _run_child(family, schemes="helios,syn,st_only", rounds=3):
+def _child(*args, tag="EQUIV"):
     env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
                REPRO_HOST_DEVICES="16")
     cmd = [sys.executable, os.path.join(REPO, "tests",
-                                        "sharded_equiv_child.py"),
-           "--family", family, "--schemes", schemes,
-           "--rounds", str(rounds)]
+                                        "sharded_equiv_child.py"), *args]
     r = subprocess.run(cmd, env=env, capture_output=True, text=True,
                        timeout=1800)
     assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
-    recs = [json.loads(line[len("EQUIV "):])
-            for line in r.stdout.splitlines() if line.startswith("EQUIV ")]
+    return [json.loads(line[len(tag) + 1:])
+            for line in r.stdout.splitlines() if line.startswith(tag + " ")]
+
+
+def _run_child(family, schemes="helios,syn,st_only", rounds=3):
+    recs = _child("--family", family, "--schemes", schemes,
+                  "--rounds", str(rounds))
     assert len(recs) == len(schemes.split(","))
+    for rec in recs:
+        # the device gather gives the host path's trajectory bit for bit
+        assert rec["diff_shd_host"] == 0.0, rec
+        assert rec["device_cohorts"] == rec["host_cohorts"] == rounds, rec
     return recs
+
+
+def test_sharded_gather_16dev_no_collectives():
+    """Compile-only on a 4-device client mesh: the sharded cohort gather
+    splits its rows over ``clients`` and reads each device's own copy of
+    the training set, so the program holds no collective."""
+    (rec,) = _child("--gather", tag="GATHER")
+    assert rec["n_devices"] == 16 and rec["mesh_shards"] == 4
+    assert rec["collectives"] == [], rec
+    assert rec["specs"] == ["PartitionSpec('clients',)"], rec
+    assert rec["equal"], rec
 
 
 @pytest.mark.slow
