@@ -23,10 +23,26 @@ the median leaf's are left out of the two norm gaps: they move by rounding.
 """
 from __future__ import annotations
 
+import jax
 import numpy as np
 
 NAMES = ("loss_gap", "update1_gap", "change3_gap", "mask_units_differ",
          "selected_units_gap")
+
+
+def leaves(tree, is_leaf=None) -> dict:
+    """A tree's leaves by path (``"blocks/attn/wq"``); a flat dict keeps
+    its keys."""
+    flat = jax.tree_util.tree_flatten_with_path(tree, is_leaf=is_leaf)[0]
+    return {"/".join(str(getattr(k, "key", getattr(k, "idx", k)))
+                     for k in path): leaf for path, leaf in flat}
+
+
+def host_leaves(tree) -> dict:
+    """``leaves`` of a tree of device arrays, copied to the host as
+    float32."""
+    return {k: np.array(v, np.float32)
+            for k, v in leaves(jax.device_get(tree)).items()}
 
 
 def _norms(after: dict, before: dict) -> dict:
@@ -50,9 +66,9 @@ def moving_leaves(ref_first: dict, before: dict) -> list:
 
 def readings(prog: dict, ref: dict, before: dict, units: int) -> dict:
     """``prog``/``ref``: {"losses": [...], "ratios": [[...] per round],
-    "masks": {cid: {unit type: (n,)}} of round 1, "params": [after round 1,
-    after the last round]} (host float32); ``units``: the model's maskable
-    units."""
+    "masks": {cid: {unit type: (rows, n)}} of round 1, "params": [after
+    round 1, after the last round]} (host float32, leaves by path);
+    ``units``: the model's maskable units over every row."""
     keep = moving_leaves(ref["params"][0], before)
     differ = 0
     for cid, want in ref["masks"].items():

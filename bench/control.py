@@ -36,14 +36,13 @@ VARIANTS = {"bf16": {"dtype": "bfloat16", "precision": None},
 def reading(workload: dict, seed: int, variant: str, cfg=None,
             traffic=None) -> dict:
     import jax.numpy as jnp
-    import numpy as np
-    from bench import compare, models, run
+    from bench import compare, run
     from bench.reference import Reference
     from bench.world import make_world
 
     world = make_world(workload, seed, cfg, traffic)
     rounds = world.traffic["check_rounds"]
-    before = {k: np.array(v, np.float32) for k, v in world.weights.items()}
+    before = compare.host_leaves(world.weights)
     if variant == "program":
         engine, before, prog = run.set_up(world)
         del engine
@@ -53,9 +52,7 @@ def reading(workload: dict, seed: int, variant: str, cfg=None,
             kw["dtype"] = getattr(jnp, kw["dtype"])
         prog = compare.replay(Reference(world, **kw), rounds)
     ref = compare.replay(Reference(world), rounds)
-    units = sum(models.load(world.cfg["model"]).mask_units(world.cfg)
-                .values())
-    return compare.readings(prog, ref, before, units)
+    return compare.readings(prog, ref, before, world.units)
 
 
 def main(argv=None) -> int:

@@ -1,9 +1,10 @@
 """Operations and bytes that a federated round requires, from shapes.
 
 Counts are of the work the algorithm needs, not of what the program does:
-a straggler's sub-model counts only its alive units (a layer's MACs scale
-with alive inputs x alive outputs), the first layer's input gradient is not
-needed, and padding is not work.  One multiply-add is two operations.
+a straggler's sub-model counts only its alive units, and padding is not
+work.  One multiply-add is two operations.  What a model needs per example
+comes from its configuration's module (``bench/models/<model>.py``); the
+kernels' own formulas are here.
 """
 from __future__ import annotations
 
@@ -28,43 +29,23 @@ def peaks(device_kind: str) -> dict:
     return table[device_kind]
 
 
-def layer_macs(layer: dict, alive: dict | None) -> int:
-    """MACs of one layer for one image; ``alive`` maps unit type -> alive
-    count (None: the whole model)."""
-    cin, cout = layer["cin"], layer["cout"]
-    if alive is not None and layer["in_mask"] is not None:
-        cin = alive[layer["in_mask"]] * layer["in_rep"]
-    if alive is not None and layer["out_mask"] is not None:
-        cout = alive[layer["out_mask"]]
-    return layer["positions"] * layer["kk"] * cin * cout
-
-
-def forward_macs(cfg: dict, alive: dict | None = None) -> int:
-    return sum(layer_macs(l, alive)
-               for l in models.load(cfg["model"]).layers(cfg))
-
-
-def train_ops(cfg: dict, alive: dict | None = None) -> int:
-    """Forward and backward operations of one image: forward 2 MACs, weight
-    gradient 2, input gradient 2 (not for the layer that reads the
-    image)."""
-    total = 0
-    for l in models.load(cfg["model"]).layers(cfg):
-        macs = layer_macs(l, alive)
-        total += macs * (4 if l["first"] else 6)
-    return total
-
-
 def masked_matmul_cost(batch: int, k: int, n_alive: int) -> tuple:
     """(ops, bytes) of one masked dense layer's three kernel calls over its
-    alive output columns: y = x W, dx = dy W^T, dW = x^T dy, float32."""
+    alive columns: y = x W, dx = dy W^T, dW = x^T dy, float32.  A masked
+    contraction over alive rows, (batch, n_alive) @ (n_alive, k), costs
+    the same."""
     ops = 3 * 2 * batch * k * n_alive
     words = (batch * k + k * n_alive + batch * n_alive) * 3
     return ops, 4 * words
 
 
-def alive_units(masks: dict) -> dict:
-    return {k: int(np.sum(np.asarray(v) > 0)) for k, v in masks.items()}
+def flash_attention_cost(batch: int, seq: int, heads: int,
+                         head_dim: int) -> tuple:
+    """(ops, bytes) of one causal flash-attention forward call, float32:
+    QK^T and AV at their causal share (S^2/2 query-key pairs each), and q,
+    k, v read and the output written once."""
+    ops = 2 * batch * heads * seq * seq * head_dim
+    return ops, 4 * 4 * batch * heads * seq * head_dim
 
 
 def alive_block_units(mask, block: int) -> int:
@@ -86,27 +67,28 @@ class RoundCost:
     def __init__(self, world, cohorts: list, masks: dict):
         cfg, tr = world.cfg, world.traffic
         mod = models.load(cfg["model"])
-        images = tr["local_steps"] * tr["batch"]
-        full = train_ops(cfg) * images
-        self.eval_ops = 2 * forward_macs(cfg) * tr["eval_images"]
-        per = {i: train_ops(cfg, alive_units(m)) * images
+        examples = tr["local_steps"] * tr["batch"]
+        full = mod.train_ops(cfg, tr) * examples
+        self.eval_ops = mod.forward_ops(cfg, tr) * mod.examples(tr)[1]
+        per = {i: mod.train_ops(cfg, tr, m) * examples
                for i, m in masks.items()}
         self.train_ops = float(np.mean(
             [sum(per.get(i, full) for i in c) for c in cohorts]))
         self.ops_per_round = self.train_ops + self.eval_ops
-        kops = kbytes = 0
+        total = {}
         if tr["kernels"] == "pallas":
-            layers = {l["name"]: l for l in mod.layers(cfg)}
             for c in cohorts:
                 for i in c:
-                    for name in mod.masked_matmul_layers(cfg):
-                        l = layers[name]
-                        n = l["cout"] if i not in masks else \
-                            alive_block_units(masks[i][name],
-                                              tr["mask_block"])
-                        o, b = masked_matmul_cost(tr["batch"], l["cin"], n)
-                        kops += o * tr["local_steps"]
-                        kbytes += b * tr["local_steps"]
-        #: per round, summed over every client's kernel calls
-        self.kernel_ops = kops / len(cohorts)
-        self.kernel_bytes = kbytes / len(cohorts)
+                    costs = mod.kernel_costs(cfg, tr, masks.get(i))
+                    for name, (o, b) in costs.items():
+                        to, tb = total.get(name, (0, 0))
+                        total[name] = (to + o * tr["local_steps"],
+                                       tb + b * tr["local_steps"])
+        #: per round, summed over every client's calls: {kernel: (ops,
+        #: bytes)}
+        self.kernels = {k: (o / len(cohorts), b / len(cohorts))
+                        for k, (o, b) in total.items()}
+        #: the masked-matmul pair's, as ``metrics/masked_matmul_roofline``
+        #: reads them
+        self.kernel_ops, self.kernel_bytes = self.kernels.get(
+            "masked_matmul", (0.0, 0.0))

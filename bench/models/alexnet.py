@@ -10,6 +10,13 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from bench.models._cnn import (examples, forward_ops, init,  # noqa: F401
+                               kernel_costs, loss, make_data,
+                               program_config, train_ops, unit_scores)
+
+#: the configuration's key that the program's ``cnn_channels`` takes
+WIDTHS = "conv_channels"
+
 
 def _side(cfg) -> int:
     return cfg["image_size"] // 2 ** len(cfg["pool_after"])
@@ -30,14 +37,14 @@ def param_shapes(cfg) -> dict:
 
 
 def mask_units(cfg) -> dict:
-    """Maskable unit types in schema order: name -> unit count."""
-    out = {f"conv{i}": c for i, c in enumerate(cfg["conv_channels"])}
-    out.update({f"fc{i}": n for i, n in enumerate(cfg["fc_units"])})
+    """Maskable unit types in schema order: name -> (1, unit count)."""
+    out = {f"conv{i}": (1, c) for i, c in enumerate(cfg["conv_channels"])}
+    out.update({f"fc{i}": (1, n) for i, n in enumerate(cfg["fc_units"])})
     return out
 
 
 def logits(params, x, cfg, masks, precision):
-    """masks: {unit type: (n,)} 0/1, or None for the whole model."""
+    """masks: {unit type: (1, n)} 0/1, or None for the whole model."""
     def mask(h, key):
         return h if masks is None else h * masks[key].astype(h.dtype)
 

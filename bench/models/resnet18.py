@@ -11,6 +11,13 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from bench.models._cnn import (examples, forward_ops, init,  # noqa: F401
+                               kernel_costs, loss, make_data,
+                               program_config, train_ops, unit_scores)
+
+#: the configuration's key that the program's ``cnn_channels`` takes
+WIDTHS = "stage_channels"
+
 
 def param_shapes(cfg) -> dict:
     ws = cfg["stage_channels"]
@@ -30,8 +37,8 @@ def param_shapes(cfg) -> dict:
 
 
 def mask_units(cfg) -> dict:
-    return {f"s{s}b{b}c0": w for s, w in enumerate(cfg["stage_channels"])
-            for b in range(2)}
+    return {f"s{s}b{b}c0": (1, w)
+            for s, w in enumerate(cfg["stage_channels"]) for b in range(2)}
 
 
 def _group_norm(x, groups, eps=1e-5):

@@ -5,20 +5,24 @@ replays the first rounds of the cell one client at a time:
 
 * the cohort: every client, or ``participation`` clients drawn uniformly
   without replacement from ``default_rng((seed, 0x5EED))``, sorted;
-* each client's batches: ``local_steps x batch`` rows drawn from its own
-  examples by ``default_rng(seed)``, in cohort order;
+* each client's batches: ``local_steps x batch`` examples drawn from its
+  own by ``default_rng(seed)``, in cohort order, every key of the batch
+  dict indexed alike;
 * each straggler's unit masks by Eq. 2 at its volume P: top-``p_s`` by the
   previous cycle's contribution, a random rest, and units skipped for more
   than ``1 + 1/P`` cycles forced in; unit types at least four mask blocks
   wide are selected by whole blocks of block-mean scores;
 * local training: ``local_steps`` of SGD with momentum from zero, on the
-  mean cross-entropy, masks multiplying the activations;
-* Eq. 1 scores (summed absolute change of each unit's weights and bias)
-  and skip counters;
+  configuration's loss (``bench/models/<model>.py``), masks multiplying
+  the units' activations;
+* Eq. 1 scores (the model's ``unit_scores``: the summed absolute change of
+  every weight that carries the unit's axis) and skip counters;
 * Eq. 10 aggregation: the mean of the client models weighted by each
   client's selected fraction of units;
 * Sec. IV.C volume adaptation toward the median capable time.
 
+Masks, scores and skip counters are ``(rows, n)`` per unit type, one row
+per layer that the type spans (``mask_units``); Eq. 2 selects row by row.
 Volumes start at pace / straggler time (Table I speed factors).  The random
 streams are JAX's threefry keys and NumPy's PCG64 from the seeds named
 above: the same seed gives the same cohorts, batches and masks as the
@@ -36,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from bench import models
+from bench.compare import host_leaves
 
 
 def _row_select(u, forced, k_total, k_top, key):
@@ -52,8 +57,8 @@ def _row_select(u, forced, k_total, k_top, key):
 
 
 def select(scores, forced, volume, p_s, key, block=0):
-    """Eq. 2 over unit types ``{name: (1, n)}``; block-granular for unit
-    types at least ``4 * block`` wide."""
+    """Eq. 2 over unit types ``{name: (rows, n)}``, row by row;
+    block-granular for unit types at least ``4 * block`` wide."""
     if block:
         pooled = [k for k, u in scores.items() if u.shape[-1] >= 4 * block]
         if not pooled:
@@ -125,24 +130,20 @@ class Reference:
         lr, beta = tr["lr"], tr["momentum"]
         keep = int(round(tr["batch"] * batch_keep))
 
-        def loss_fn(p, x, y, masks):
-            x, y = x[:keep], y[:keep]
-            z = model.logits(p, x.astype(p["head_w"].dtype), cfg, masks,
-                             precision).astype(jnp.float32)
-            gold = jnp.take_along_axis(z, y[:, None], axis=-1)[:, 0]
-            return jnp.mean(jax.nn.logsumexp(z, axis=-1) - gold)
+        def loss_fn(p, batch, masks):
+            return model.loss(p, {k: v[:keep] for k, v in batch.items()},
+                              cfg, masks, precision)
 
-        def train(params, images, labels, masks):
+        def train(params, batches, masks):
             def step(carry, batch):
                 p, m = carry
-                loss, g = jax.value_and_grad(loss_fn)(p, *batch, masks)
+                loss, g = jax.value_and_grad(loss_fn)(p, batch, masks)
                 m = jax.tree.map(lambda mm, gg: beta * mm + gg, m, g)
                 p = jax.tree.map(lambda pp, mm: pp - lr * mm, p, m)
                 return (p, m), loss
 
             zeros = jax.tree.map(jnp.zeros_like, params)
-            (p, _), losses = jax.lax.scan(step, (params, zeros),
-                                          (images, labels))
+            (p, _), losses = jax.lax.scan(step, (params, zeros), batches)
             return p, losses.mean()
 
         return train
@@ -158,13 +159,7 @@ class Reference:
         return {**st, "masks": masks, "rng": rng}
 
     def _end_cycle(self, st, new, old):
-        scores = {}
-        for k in self.units:
-            d = jnp.abs(new[f"{k}_w"].astype(jnp.float32)
-                        - old[f"{k}_w"].astype(jnp.float32))
-            scores[k] = (d.sum(axis=tuple(range(d.ndim - 1)))
-                         + jnp.abs(new[f"{k}_b"].astype(jnp.float32)
-                                   - old[f"{k}_b"].astype(jnp.float32)))[None]
+        scores = self.model.unit_scores(new, old, self.w.cfg)
         skip = {k: jnp.where(st["masks"][k] > 0, 0, v + 1)
                 for k, v in st["skip"].items()}
         return {**st, "scores": scores, "skip": skip}
@@ -177,11 +172,11 @@ class Reference:
 
     # -- one round -----------------------------------------------------
     def _new_state(self, cid):
-        ones = {k: jnp.ones((1, n)) for k, n in self.units.items()}
+        ones = {k: jnp.ones(shape) for k, shape in self.units.items()}
         return {"masks": ones,
                 "scores": jax.tree.map(jnp.zeros_like, ones),
-                "skip": {k: jnp.zeros((1, n), jnp.int32)
-                         for k, n in self.units.items()},
+                "skip": {k: jnp.zeros(shape, jnp.int32)
+                         for k, shape in self.units.items()},
                 "volume": jnp.float32(self.volume[cid]),
                 "rng": jax.random.PRNGKey(cid)}
 
@@ -206,19 +201,19 @@ class Reference:
                                 else 1.0) for c in cohort]
         capable = [fleet[c][1] for c in cohort if not fleet[c][0]]
         pace = float(np.median(capable)) if capable else 1.0
-        total = np.float32(sum(self.units.values()))
-        ones = {k: jnp.ones((n,)) for k, n in self.units.items()}
+        total = np.float32(self.w.units)
+        ones = {k: jnp.ones(shape) for k, shape in self.units.items()}
         news, losses, ratios, masks = [], [], [], {}
         for cid, take in zip(cohort, rows):
             m = ones
             if fleet[cid][0]:
                 st = self._begin(self.state.get(cid) or self._new_state(cid))
-                masks[cid] = {k: np.asarray(v[0])
+                masks[cid] = {k: np.asarray(v)
                               for k, v in st["masks"].items()}
-                m = {k: v[0] for k, v in st["masks"].items()}
-            p, loss = self._train(self.params,
-                                  jnp.asarray(data["images"][take]),
-                                  jnp.asarray(data["labels"][take]), m)
+                m = st["masks"]
+            p, loss = self._train(
+                self.params, {k: jnp.asarray(v[take])
+                              for k, v in data.items()}, m)
             if fleet[cid][0]:
                 self.state[cid] = self._end(st, p, self.params)
             alive = sum(float(jnp.sum(v)) for v in m.values())
@@ -239,4 +234,4 @@ class Reference:
         return float(np.mean(losses)), ratios, masks
 
     def host_params(self):
-        return {k: np.asarray(v, np.float32) for k, v in self.params.items()}
+        return host_leaves(self.params)

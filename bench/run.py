@@ -83,13 +83,6 @@ def _compile_counter() -> collections.Counter:
     return events
 
 
-def _host(tree) -> dict:
-    import jax
-    import numpy as np
-    return {k: np.array(v, np.float32)
-            for k, v in jax.device_get(tree).items()}
-
-
 def _masks(run, cids) -> dict:
     """Each listed client's current unit masks, as the engine keeps them
     (copied: the sharded engine updates its host-side rows in place)."""
@@ -100,17 +93,18 @@ def _masks(run, cids) -> dict:
     for i in cids:
         st = run.client_state(i) if hasattr(run, "client_state") \
             else run.clients[i].helios_state
-        out[i] = {k: np.array(v).reshape(-1) for k, v in st["masks"].items()}
+        out[i] = {k: np.array(v) for k, v in st["masks"].items()}
     return out
 
 
 def set_up(world):
     """Build the engine and drive the first ``check_rounds`` rounds through
     ``run_sync``; returns the engine and what the comparison reads."""
+    from bench.compare import host_leaves
     from bench.world import build_engine
     tr = world.traffic
     run = build_engine(world)
-    before = _host(run.global_params)
+    before = host_leaves(run.global_params)
     prog = {"losses": [], "ratios": [], "masks": {}, "params": []}
     for r in range(tr["check_rounds"]):
         run.run_sync(1, eval_every=tr["eval_every"])
@@ -120,8 +114,8 @@ def set_up(world):
         if r == 0:
             strag = [i for i in run.cohort_log[-1] if world.fleet[i][0]]
             prog["masks"] = _masks(run, strag)
-            prog["params"].append(_host(run.global_params))
-    prog["params"].append(_host(run.global_params))
+            prog["params"].append(host_leaves(run.global_params))
+    prog["params"].append(host_leaves(run.global_params))
     return run, before, prog
 
 
@@ -224,11 +218,12 @@ def end_to_end_for(bench: dict, name: str) -> list:
 
 
 def run_cell(bench: dict, workload: dict, seed: int, seconds: float,
-             trace: bool, cfg=None, traffic=None) -> dict:
-    """Everything after the platform check; returns the result line."""
+             trace: bool, cfg=None, traffic=None, limits=None) -> dict:
+    """Everything after the platform check; returns the result line.
+    ``cfg``, ``traffic`` and ``limits`` default to the cell's files (tests
+    pass their own small ones)."""
     import jax
-    import numpy as np
-    from bench import compare, flops, models, trace_reduce
+    from bench import compare, flops, trace_reduce
     from bench.reference import Reference
     from bench.world import make_world
 
@@ -237,7 +232,7 @@ def run_cell(bench: dict, workload: dict, seed: int, seconds: float,
     tr = world.traffic
     run, before, prog = set_up(world)
     setup_s = time.perf_counter() - T0
-    devices = sorted(next(iter(run.global_params.values())).sharding
+    devices = sorted(jax.tree.leaves(run.global_params)[0].sharding
                      .device_set, key=lambda d: d.id)
 
     metrics, device, breakdown, host = {}, {}, None, None
@@ -293,12 +288,11 @@ def run_cell(bench: dict, workload: dict, seed: int, seconds: float,
     del run, host                      # the spans' wrappers hold the engine
     gc.collect()
     ref = compare.replay(Reference(world), tr["check_rounds"])
-    with open(os.path.join(ROOT, "bench", "limits",
-                           workload["name"] + ".json")) as f:
-        limits = json.load(f)
-    units = sum(models.load(world.cfg["model"]).mask_units(world.cfg)
-                .values())
-    checks = compare.judge(compare.readings(prog, ref, before, units),
+    if limits is None:
+        with open(os.path.join(ROOT, "bench", "limits",
+                               workload["name"] + ".json")) as f:
+            limits = json.load(f)
+    checks = compare.judge(compare.readings(prog, ref, before, world.units),
                            limits)
     result = {"correct": all(c["ok"] for c in checks.values()),
               "attempted": n_rounds, "failed": failed, "metrics": metrics,

@@ -1,7 +1,9 @@
 """The comparison that decides ``correct``, driven through the rest of a run
 at a size the CPU can hold: a sound run passes; the control (the reference
 in bfloat16 in the engine's place) and each fault that a training cell can
-have, planted in the engine, fail."""
+have, planted in the engine, fail.  The same for the tiny decoder
+(``data/tiny-decoder.json``), which has no cell: it proves that a token LM
+goes through the harness as a configuration and its module alone."""
 import json
 import os
 import subprocess
@@ -60,15 +62,14 @@ def _stuck(monkeypatch):
 
 
 def _half_batch(monkeypatch):
-    from repro.federated.adapter import CNNAdapter
-    loss = CNNAdapter.loss_fn
+    from repro.federated.adapter import CNNAdapter, TokenLMAdapter
+    for cls in (CNNAdapter, TokenLMAdapter):
+        def half(self, params, batch, masks, loss=cls.loss_fn):
+            keep = next(iter(batch.values())).shape[0] // 2
+            return loss(self, params,
+                        {k: v[:keep] for k, v in batch.items()}, masks)
 
-    def half(self, params, batch, masks):
-        keep = batch["labels"].shape[0] // 2
-        return loss(self, params, {k: v[:keep] for k, v in batch.items()},
-                    masks)
-
-    monkeypatch.setattr(CNNAdapter, "loss_fn", half)
+        monkeypatch.setattr(cls, "loss_fn", half)
 
 
 @pytest.mark.parametrize("plant", [_stuck, _half_batch],
@@ -76,6 +77,51 @@ def _half_batch(monkeypatch):
 def test_fault_fails(monkeypatch, plant):
     plant(monkeypatch)
     res = _run()
+    assert not res["correct"], res["checks"]
+
+
+DECODER = {"name": "tiny-decoder", "config": "tiny-decoder", "chips": 1}
+#: the program masks a head's query (q * mask), not its output: a masked
+#: head still sends the causal mean of its values through wo, and its wv
+#: and wo train.  The plain reference, which multiplies the head's output
+#: as the published mechanism has it, then departs in every straggler's
+#: update (update1_gap 0.044, change3_gap 0.067, loss_gap 5.1e-4 at seed
+#: 2**33 + 5); with the mask moved onto q in the reference the gaps read
+#: 2e-8 to 4e-8.
+Q_MASKED_HEADS = pytest.mark.xfail(
+    strict=True, reason="the program masks a head's q, not its output")
+
+
+def _decoder_run(traffic, seed=2 ** 33 + 5):
+    return run.run_cell(run.load_benchmark(), DECODER, seed, 1.0, False,
+                        _tiny("tiny-decoder"), _tiny(traffic),
+                        _tiny("tiny-decoder.limits"))
+
+
+@pytest.mark.parametrize("traffic", [
+    "tiny-token-sync",
+    pytest.param("tiny-token-stragglers", marks=Q_MASKED_HEADS)])
+def test_token_lm_sound_run_is_correct(traffic):
+    res = _decoder_run(traffic)
+    assert list(res["checks"]) == list(compare.NAMES)
+    assert res["attempted"] >= 2 and res["failed"] == 0
+    assert res["correct"], res["checks"]
+
+
+@pytest.mark.parametrize("traffic", ["tiny-token-sync",
+                                     "tiny-token-stragglers"])
+def test_token_lm_control_fails(traffic):
+    got = control.reading(DECODER, 17, "bf16", _tiny("tiny-decoder"),
+                          _tiny(traffic))
+    judged = compare.judge(got, _tiny("tiny-decoder.limits"))
+    assert not all(c["ok"] for c in judged.values()), judged
+
+
+@pytest.mark.parametrize("plant", [_stuck, _half_batch],
+                         ids=["state_unchanged", "half_batch"])
+def test_token_lm_fault_fails(monkeypatch, plant):
+    plant(monkeypatch)
+    res = _decoder_run("tiny-token-sync")
     assert not res["correct"], res["checks"]
 
 

@@ -5,7 +5,8 @@ import os
 import numpy as np
 import pytest
 
-from bench import flops
+from bench import flops, models
+from bench.models import _cnn
 
 CONFIGS = os.path.join(os.path.dirname(__file__), "..", "configs")
 
@@ -23,7 +24,9 @@ def test_alexnet_forward_macs_by_hand():
             + 64 * 3456 * 256 + 64 * 2304 * 256 + 4096 * 1024 + 1024 * 512
             + 512 * 10)
     assert hand == 171_643_904
-    assert flops.forward_macs(_cfg("alexnet-cifar10")) == hand
+    assert _cnn.forward_macs(_cfg("alexnet-cifar10")) == hand
+    assert models.load("alexnet").forward_ops(_cfg("alexnet-cifar10"),
+                                              {}) == 2 * hand
 
 
 def test_resnet18_forward_macs_by_hand():
@@ -34,24 +37,23 @@ def test_resnet18_forward_macs_by_hand():
         later += pos * 9 * cin * w + 3 * pos * 9 * w * w + pos * cin * w
     hand = 1024 * 27 * 64 + stage0 + later + 512 * 100
     assert hand == 555_468_800
-    assert flops.forward_macs(_cfg("resnet18-cifar100")) == hand
+    assert _cnn.forward_macs(_cfg("resnet18-cifar100")) == hand
 
 
 def test_train_ops_full_masks():
     cfg = _cfg("alexnet-cifar10")
+    mod = models.load("alexnet")
     conv0 = 1024 * 27 * 64
-    assert flops.train_ops(cfg) == 6 * flops.forward_macs(cfg) - 2 * conv0
-    full = {k: n for k, n in
-            {"conv0": 64, "conv1": 192, "conv2": 384, "conv3": 256,
-             "conv4": 256, "fc0": 1024, "fc1": 512}.items()}
-    assert flops.train_ops(cfg, full) == flops.train_ops(cfg)
+    assert mod.train_ops(cfg, {}) == 6 * _cnn.forward_macs(cfg) - 2 * conv0
+    full = {k: np.ones(shape) for k, shape in mod.mask_units(cfg).items()}
+    assert mod.train_ops(cfg, {}, full) == mod.train_ops(cfg, {})
 
 
 def test_sub_model_counts_alive_inputs_and_outputs():
     cfg = _cfg("alexnet-cifar10")
     alive = {"conv0": 32, "conv1": 96, "conv2": 384, "conv3": 256,
              "conv4": 128, "fc0": 256, "fc1": 512}
-    macs = flops.forward_macs(cfg, alive)
+    macs = _cnn.forward_macs(cfg, alive)
     hand = (1024 * 27 * 32 + 256 * 9 * 32 * 96 + 64 * 9 * 96 * 384
             + 64 * 3456 * 256 + 64 * 2304 * 128 + 16 * 128 * 256
             + 256 * 512 + 512 * 10)
